@@ -295,20 +295,14 @@ class CalibrationProfile:
 def _dry_run_counts(fn, args: Sequence[Any],
                     kwargs: Dict[str, Any]) -> Tuple[float, float, Any]:
     """(flops, bytes_accessed, callable) via the §Roofline dry-run path:
-    jit → lower → compile → ``cost_analysis()``.  Falls back to the raw
-    function (0 FLOPs/bytes) for kernels XLA cannot lower as-is."""
+    jit → lower → compile → ``cost_analysis()``.  A kernel that does not
+    compile is an error, not a zero-cost kernel."""
     import jax
-    try:
-        jitted = jax.jit(fn)
-        compiled = jitted.lower(*args, **kwargs).compile()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, list):          # older jax returns [dict]
-            cost = cost[0]
-        flops = max(float(cost.get("flops", 0.0)), 0.0)
-        nbytes = max(float(cost.get("bytes accessed", 0.0)), 0.0)
-        return flops, nbytes, jitted
-    except Exception:
-        return 0.0, 0.0, fn
+    jitted = jax.jit(fn)
+    cost = jitted.lower(*args, **kwargs).compile().cost_analysis()
+    flops = max(float(cost.get("flops", 0.0)), 0.0)
+    nbytes = max(float(cost.get("bytes accessed", 0.0)), 0.0)
+    return flops, nbytes, jitted
 
 
 def profile_kernels(table: Any,

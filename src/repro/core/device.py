@@ -44,6 +44,23 @@ from .mediary import HostMirror, MediaryStore, PresentTable
 SLOT_STREAM = -1
 
 
+def host_array(value: Any) -> Any:
+    """``value`` as an array without placing it on any device.
+
+    A ``jax.Array`` passes through where it lives; anything else becomes a
+    NumPy array of the dtype JAX would give it.  The pool places host values
+    on their target device directly, so a transfer to device ``d`` never
+    stages through the process's default device first.
+    """
+    if isinstance(value, jax.Array):
+        return value
+    a = np.asarray(value)
+    if a.dtype.kind in "OSUMm":         # what jnp.asarray refuses, refused
+        raise TypeError(f"value of dtype {a.dtype} is not a valid JAX "
+                        f"array type")
+    return a.astype(jax.dtypes.canonicalize_dtype(a.dtype), copy=False)
+
+
 @dataclass(frozen=True)
 class Command:
     op: str                 # ALLOC | FREE | XFER_TO | XFER_FROM | EXEC |
@@ -75,18 +92,20 @@ class NodeDevice:
         self.jax_device = jax_device
         self.sharding = sharding
         self.store = MediaryStore(sharding=sharding)
+        # where this device's buffers live: its sharding, else its jax device
+        self.placement = sharding if sharding is not None else jax_device
         self.stopped = False
         # resident-memory budget for this device's present table (None =
         # unbounded); enforced by the executor's LRU spill path, not here
         self.capacity_bytes = capacity_bytes
         self._jit_cache: Dict[int, Callable] = {}
 
-    def _place(self, value: jax.Array) -> jax.Array:
-        if self.sharding is not None:
-            return jax.device_put(value, self.sharding)
-        if self.jax_device is not None:
-            return jax.device_put(value, self.jax_device)
-        return value
+    def _place(self, value: Any) -> jax.Array:
+        """Put ``value`` on this device; an array already there is aliased
+        (``device_put`` to its own placement copies nothing)."""
+        if self.placement is None:
+            return value
+        return jax.device_put(value, self.placement)
 
     # -- the device-side command loop (paper §4.1) --------------------------
     def execute(self, cmd: Command, table: KernelTable,
@@ -329,8 +348,9 @@ class DevicePool:
 
     ``DevicePool.from_config(["node0 2", "node1"])`` yields 3 devices, the
     first two being virtual shares of node0 — the paper's multiplier feature.
-    On this CPU container, every hostname resolves to the single CpuDevice;
-    on a pod, pass explicit shardings (one mesh sub-slice per device).
+    Every hostname resolves to the process's first JAX device, so a config
+    pool is virtual shares of one chip; :meth:`from_mesh_slices` gives one
+    device per chip (or per mesh sub-slice) instead.
 
     Commands flow through a **dependency-aware per-device stream** drained by
     one worker thread per device (the paper's device-side command loop made
@@ -364,8 +384,10 @@ class DevicePool:
         # a blown deadline raises StragglerTimeout instead of waiting forever
         # on a wedged worker.  None (default) = wait indefinitely.
         self.deadline_s = deadline_s
-        # observability: blown deadlines by op (guarded by _trace_lock)
+        # observability: blown deadlines by op, and stashed DeviceFailures
+        # that recovery cleared (both guarded by _trace_lock)
         self.straggler_timeouts: Dict[str, int] = {}
+        self.failures_absorbed = 0
         # shared failure bookkeeping consulted by placement policies
         self.health = HealthRegistry()
         self.mirrors = [HostMirror() for _ in self.devices]
@@ -556,6 +578,8 @@ class DevicePool:
                 if isinstance(err, DeviceFailure):
                     self._async_errors[d] = None
                     absorbed.append(err)
+        with self._trace_lock:
+            self.failures_absorbed += len(absorbed)
         return absorbed
 
     def _traced(self, device: int, cmd: Command,
@@ -677,7 +701,9 @@ class DevicePool:
 
     def transfer_to(self, device: int, handle: int, value: Any,
                     section: Optional[slice] = None, tag: str = "") -> "_cf.Future":
-        value = jnp.asarray(value)
+        # copy at issue time (a later change to a host array must not reach
+        # the device), straight onto the target device
+        value = self.devices[device]._place(host_array(value))
         nbytes = value.size * value.dtype.itemsize
         with self.locks[device]:
             cmd = Command("XFER_TO", device, handle=handle, nbytes=nbytes,
@@ -853,9 +879,18 @@ class DevicePool:
         Appends every piece of per-device parallel state, starts the worker
         thread, and replays ``install_global`` history onto the newcomer so
         declare-target globals resolve there too.  Returns the new index.
+
+        The newcomer is one more share of the placement every device of the
+        pool already shares (virtual shares of one chip).  A pool spread
+        over several chips raises: no chip is the obvious home for it.
         """
         i = len(self.devices)
-        dev = NodeDevice(i, jax_device=jax.devices()[0],
+        homes = {(d.jax_device, d.sharding) for d in self.devices}
+        if len(homes) > 1:
+            raise ValueError(f"cannot grow a pool spread over {len(homes)} "
+                             f"placements")
+        jax_device, sharding = next(iter(homes), (jax.devices()[0], None))
+        dev = NodeDevice(i, jax_device=jax_device, sharding=sharding,
                          hostname=hostname or f"vnode{i}",
                          capacity_bytes=capacity_bytes)
         self.devices.append(dev)
@@ -934,7 +969,7 @@ class DevicePool:
         one-shot broadcast cost is recorded (it is what makes the alignment
         workload scale: invariant data moves once).
         """
-        value = jnp.asarray(value)
+        value = host_array(value)
         if name in self.globals:            # idempotent re-install (re-runs)
             old = self.globals.pop(name)
             for i, h in old.items():

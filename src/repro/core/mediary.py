@@ -58,6 +58,19 @@ class SlotTableBase:
         return len(self._slots)
 
 
+@dataclass(frozen=True)
+class _Calloc:
+    """A slot ALLOC reserved and nothing has written yet.
+
+    It reads as zeros (OMPi allocates with ``calloc()``), but the zeros are
+    made only if the slot is read before it is written: the usual ALLOC is
+    followed by a whole-buffer transfer, and zero-filling first would hold
+    a second buffer of the same size on the device until that transfer ran.
+    """
+    shape: Tuple[int, ...]
+    dtype: Any
+
+
 class MediaryStore(SlotTableBase):
     """Device-side mediary array: handle → actual buffer (paper: calloc'd ptr)."""
 
@@ -69,10 +82,8 @@ class MediaryStore(SlotTableBase):
     def alloc(self, shape: Sequence[int], dtype: Any) -> int:
         """ALLOC: zero-initialized, as OMPi uses ``calloc()``."""
         handle = self._first_free()
-        buf = jnp.zeros(tuple(shape), dtype=dtype)
-        if self._sharding is not None:
-            buf = jax.device_put(buf, self._sharding)
-        self._slots[handle] = buf
+        self._slots[handle] = _Calloc(tuple(shape),
+                                      jax.dtypes.canonicalize_dtype(dtype))
         return handle
 
     def install(self, handle: int, value: jax.Array) -> None:
@@ -85,10 +96,10 @@ class MediaryStore(SlotTableBase):
 
     def write(self, handle: int, value: jax.Array, section: Optional[slice] = None) -> None:
         """TRANSFER_TO: host → device (optionally into an array section)."""
-        cur = self._lookup(handle)
+        cur = self._live(handle)
         value = jnp.asarray(value, dtype=cur.dtype)
         if section is not None:
-            cur = cur.at[section].set(value)
+            cur = self._lookup(handle).at[section].set(value)
         else:
             if value.shape != cur.shape:
                 raise ValueError(f"shape mismatch {value.shape} vs {cur.shape}")
@@ -102,10 +113,17 @@ class MediaryStore(SlotTableBase):
         cur = self._lookup(handle)
         return cur[section] if section is not None else cur
 
-    def _lookup(self, handle: int) -> jax.Array:
+    def _live(self, handle: int) -> Any:
         if not (0 <= handle < len(self._slots)) or self._slots[handle] is None:
             raise KeyError(f"mediary handle {handle} is not live")
         return self._slots[handle]
+
+    def _lookup(self, handle: int) -> jax.Array:
+        cur = self._live(handle)
+        if isinstance(cur, _Calloc):     # first read of an unwritten slot
+            cur = self._slots[handle] = jnp.zeros(cur.shape, cur.dtype,
+                                                  device=self._sharding)
+        return cur
 
     # Device addresses (paper fig. 1 right column) — for tracing/debugging.
     def device_address(self, handle: int):

@@ -84,11 +84,21 @@ class RuntimeConfig:
 
 
 class ClusterRuntime:
-    def __init__(self, cfg: RuntimeConfig, table: Optional[KernelTable] = None) -> None:
+    def __init__(self, cfg: RuntimeConfig, table: Optional[KernelTable] = None,
+                 *, pool: Optional[DevicePool] = None) -> None:
+        """``pool`` runs the runtime on a pool built elsewhere — e.g.
+        ``DevicePool.from_mesh_slices`` for one device per chip — in place
+        of the one ``cfg.nodes`` / ``cfg.n_virtual`` describe.  The pool
+        then brings its own kernel table, link, capacity and deadline."""
         if cfg.comm_mode not in ("host-mediated", "direct"):
             raise ValueError(f"unknown comm_mode {cfg.comm_mode!r}")
         self.cfg = cfg
-        if cfg.n_virtual is not None:
+        if pool is not None:
+            if cfg.nodes or cfg.n_virtual is not None or table is not None:
+                raise ValueError("pass either a pool or cfg.nodes / "
+                                 "cfg.n_virtual / table, not both")
+            self.pool = pool
+        elif cfg.n_virtual is not None:
             self.pool = DevicePool.virtual(
                 cfg.n_virtual, table=table, link=cfg.link,
                 capacity_bytes=cfg.device_capacity_bytes,

@@ -55,7 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .device import DeviceFailure, DevicePool, DeviceStoppedError, StreamTicket
+from .device import (DeviceFailure, DevicePool, DeviceStoppedError,
+                     StreamTicket, host_array)
 from .mediary import PresentEntry, same_treedef
 
 
@@ -69,7 +70,7 @@ class Section:
 
     @property
     def value(self):
-        return jnp.asarray(self.array)[self.start:self.start + self.length]
+        return host_array(self.array)[self.start:self.start + self.length]
 
     @property
     def slice(self) -> slice:
@@ -126,7 +127,7 @@ class TargetFuture:
 def _as_spec(x: Any) -> jax.ShapeDtypeStruct:
     if isinstance(x, jax.ShapeDtypeStruct):
         return x
-    a = jnp.asarray(x)
+    a = host_array(x)
     return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
 
@@ -244,7 +245,7 @@ class TargetExecutor:
             if ent is None:
                 # convert before allocating: a bad leaf must fail with zero
                 # device state, and the capacity reservation needs the size
-                vals = [jnp.asarray(leaf) for leaf in leaves]
+                vals = [host_array(leaf) for leaf in leaves]
                 self._reserve_capacity(
                     device, sum(v.size * v.dtype.itemsize for v in vals),
                     tag=tag)
@@ -306,7 +307,7 @@ class TargetExecutor:
             if (not ent.device_ahead and leaf is ent.host_leaves[i]
                     and isinstance(leaf, jax.Array)):
                 continue
-            v = jnp.asarray(leaf)
+            v = host_array(leaf)
             if v.shape != ent.specs[i].shape or v.dtype != jnp.dtype(ent.specs[i].dtype):
                 raise ValueError(
                     f"resident buffer {ent.name!r} leaf {i} changed "
@@ -403,7 +404,7 @@ class TargetExecutor:
                                protect=(ent.name,))
         hs = self._alloc_specs(device, ent.specs, f"{tag}:refetch:{ent.name}")
         ent.handles = hs
-        ent.write_futs = [pool.transfer_to(device, h, jnp.asarray(leaf),
+        ent.write_futs = [pool.transfer_to(device, h, leaf,
                                            tag=f"{tag}:refetch:{ent.name}")
                           for h, leaf in zip(hs, ent.host_leaves)]
         ent.spilled = False
@@ -451,7 +452,7 @@ class TargetExecutor:
                         pool._async_errors[device] = None
                 raise err
             ent.write_futs[i] = pool.transfer_to(
-                device, ent.handles[i], jnp.asarray(leaf),
+                device, ent.handles[i], leaf,
                 tag=f"{tag}:heal:{ent.name}")
             ent.version += 1
             # the failure is handled; don't let an innocent sync op trip it
@@ -467,7 +468,7 @@ class TargetExecutor:
                 f"resident buffer {ent.name!r} structure changed; "
                 f"exit_data it first")
         for i, leaf in enumerate(leaves):
-            v = jnp.asarray(leaf)
+            v = host_array(leaf)
             if v.shape != ent.specs[i].shape or v.dtype != jnp.dtype(ent.specs[i].dtype):
                 raise ValueError(
                     f"resident buffer {ent.name!r} leaf {i} changed "
@@ -627,7 +628,7 @@ class TargetExecutor:
                                            protect=(name,))
                     dst_handles = self._alloc_specs(dst, specs, f"{tag}:{name}")
                 if src_spilled:
-                    futs = [pool.transfer_to(dst, dh, jnp.asarray(leaf),
+                    futs = [pool.transfer_to(dst, dh, leaf,
                                              tag=f"{tag}:{name}")
                             for dh, leaf in zip(dst_handles, snap.host_leaves)]
                 else:
@@ -798,7 +799,7 @@ class TargetExecutor:
                 if ent is None:
                     hs = []
                     for leaf in leaves:
-                        v = leaf.value if isinstance(leaf, Section) else jnp.asarray(leaf)
+                        v = leaf.value if isinstance(leaf, Section) else host_array(leaf)
                         h = pool.alloc(device, v.shape, v.dtype, tag=f"{tag}:{name}")
                         # the send is a dep of our EXEC: the post-EXEC check
                         # below must see ITS failure, not let it surface (and

@@ -650,7 +650,17 @@ class PeerTransport(Transport):
                 # through the host funnel (fetch + re-send), which delivers
                 # the same bytes over the paper-faithful wire
                 self.fallbacks += 1
-                value = pool.transfer_from(src, src_handle, tag=f"{tag}:fallback")
+                try:
+                    value = pool.transfer_from(src, src_handle,
+                                               tag=f"{tag}:fallback")
+                except DeviceFailure:
+                    # a timed-out SEND that fails when it finally runs
+                    # stashes its failure on src, and the fetch queued
+                    # behind it can surface that failure before the pair's
+                    # absorb callback clears it; surfacing cleared it, so
+                    # fetch again
+                    value = pool.transfer_from(src, src_handle,
+                                               tag=f"{tag}:fallback")
                 return pool.transfer_to(dst, dst_handle, value,
                                         tag=f"{tag}:fallback")
             self._backoff(attempt)

@@ -5,9 +5,9 @@ The decode-attention hot spot is HBM-bandwidth-bound: the whole cache
 rank-1 contraction.  TPU-native design: grid (batch·kv_heads, S/block_kv)
 with the cache dimension sequential; the online-softmax state (o, m, l) for
 all r = H/K query rows of the group lives in VMEM scratch, so HBM traffic is
-exactly one cache read per token — the roofline minimum.  The valid length
-``kv_len`` arrives as a scalar-prefetch operand (pl.BlockSpec(memory_space=
-SMEM) pattern via a [1] int32 input) and masks the tail block.
+exactly one cache read per token — the roofline minimum.  The per-row valid
+lengths ``kv_len`` [BK] arrive as a scalar-prefetch operand: the whole vector
+sits in SMEM and each grid row reads its own entry to mask the tail block.
 
 On a `kv-model`-sharded cache the same kernel runs per shard and the partial
 (o, m, l) combine is the psum the SPMD partitioner inserts — this kernel is
@@ -41,7 +41,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     q = q_ref[0]                                    # [r, d]
     k = k_ref[0]                                    # [bk, d]
     v = v_ref[0]
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0)]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
@@ -83,25 +83,27 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
                                block_kv=block_kv)
-    grid = (BK, nk)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                       # kv_len -> SMEM
+        grid=(BK, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,)),           # kv_len
-            pl.BlockSpec((1, r, d), lambda b, j: (b, 0, 0)),  # q
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, r, d), lambda b, j, lens: (b, 0, 0)),  # q
+            pl.BlockSpec((1, block_kv, d), lambda b, j, lens: (b, j, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, j, lens: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, r, d), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((BK, r, d), q.dtype),
+        out_specs=pl.BlockSpec((1, r, d), lambda b, j, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((r, d), jnp.float32),     # acc
             pltpu.VMEM((r,), jnp.float32),       # m
             pltpu.VMEM((r,), jnp.float32),       # l
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((BK, r, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ) if not interpret else None,
         interpret=interpret,
-    )(kv_len, q, k_cache, v_cache)
+    )(jnp.asarray(kv_len, jnp.int32), q, k_cache, v_cache)

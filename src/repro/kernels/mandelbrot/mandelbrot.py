@@ -23,22 +23,23 @@ def _mandel_kernel(o_ref, *, x0: float, dx: float, y0: float, dy: float,
     cx = x0 + cols.astype(jnp.float32) * dx
     cy = y0 + rows.astype(jnp.float32) * dy
 
+    # An escaped point's z is frozen with |z|^2 > 4, so the escape test on
+    # the carried z alone says whether the point is still iterating.
     def body(_, state):
-        zx, zy, count, alive = state
+        zx, zy, count = state
         zx2, zy2 = zx * zx, zy * zy
-        nzx = zx2 - zy2 + cx
-        nzy = 2.0 * zx * zy + cy
-        alive_new = alive & (zx2 + zy2 <= 4.0)
-        zx = jnp.where(alive_new, nzx, zx)
-        zy = jnp.where(alive_new, nzy, zy)
-        count = count + alive_new.astype(jnp.int32)
-        return zx, zy, count, alive_new
+        alive = zx2 + zy2 <= 4.0
+        zx, zy = (jnp.where(alive, zx2 - zy2 + cx, zx),
+                  jnp.where(alive, 2.0 * zx * zy + cy, zy))
+        return zx, zy, count + alive.astype(jnp.int32)
 
-    zx0 = jnp.zeros_like(cx)
-    zy0 = jnp.zeros_like(cy)
-    c0 = jnp.zeros(cx.shape, jnp.int32)
-    a0 = jnp.ones(cx.shape, bool)
-    _, _, count, _ = jax.lax.fori_loop(0, max_iter, body, (zx0, zy0, c0, a0))
+    # Mosaic gives a loop carry the vector layout of its initial value.  A
+    # constant (or cx / cy alone, each invariant along one axis) gets a
+    # replicated layout that the body's output cannot be relaid into, so the
+    # zero carries are derived from a grid that varies along both axes.
+    z0 = 0.0 * (cx + cy)
+    _, _, count = jax.lax.fori_loop(
+        0, max_iter, body, (z0, z0, z0.astype(jnp.int32)))
     o_ref[...] = count
 
 

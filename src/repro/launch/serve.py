@@ -16,6 +16,7 @@ import jax
 import numpy as np
 
 from ..configs.registry import get_config, get_smoke_config
+from .compile_cache import enable_compile_cache
 from ..models.model import Model
 from ..serve import Request, ServeConfig, ServeEngine
 
@@ -32,6 +33,7 @@ def main(argv=None) -> int:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_config(args.arch) if args.preset == "full"
            else get_smoke_config(args.arch))

@@ -37,13 +37,17 @@ from ..parallel.sharding import axis_rules
 from ..train.specs import batch_names, param_names
 from ..train.steps import (auto_policy, default_rules, make_train_step,
                            opt_state_shardings, rules_variant, _shardings_for)
+from .compile_cache import enable_compile_cache
 
 
 def build_mesh(spec: str):
     """'16x16' → mesh over (data, model); '2x16x16' adds the pod axis."""
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("pod", "data", "model")[-len(dims):]
-    return jax.make_mesh(dims, axes)
+    # Auto axes: the step shards through logical-axis constraints
+    # (parallel.sharding), which jax.make_mesh's default Explicit axes refuse
+    return jax.make_mesh(dims, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(dims))
 
 
 def main(argv=None) -> int:
@@ -66,6 +70,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_config(args.arch) if args.preset == "full"
            else get_smoke_config(args.arch))

@@ -60,8 +60,7 @@ _CTX: contextvars.ContextVar[Optional[Tuple[AxisRules, Mesh]]] = \
 def axis_rules(rules: AxisRules, mesh: Mesh):
     token = _CTX.set((rules, mesh))
     try:
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-                else contextlib.nullcontext():
+        with jax.set_mesh(mesh):
             yield
     finally:
         _CTX.reset(token)

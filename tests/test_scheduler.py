@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # container image lacks hypothesis
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (DagTask, DevicePool, KernelTable, MapSpec,
                         TargetExecutor, offload_strips, recursive_offload,

@@ -20,10 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # container image lacks hypothesis
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 

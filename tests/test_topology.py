@@ -4,10 +4,7 @@ O(D) cross-rack traffic, per-pair edge pricing, compression-aware edge
 routing, and the funnel-fallback ladder under a dead rack-leader link."""
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # container image lacks hypothesis
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

@@ -5,10 +5,7 @@ import concurrent.futures as _cf
 import threading
 import time
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # container image lacks hypothesis
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
