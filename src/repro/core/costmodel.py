@@ -71,6 +71,14 @@ class ComputeRecord:
     seconds: float          # measured or modeled task compute time
     tag: str = ""
     kernel: str = ""        # registered kernel name (placement estimates)
+    # an EXEC's parts, on its worker's clock (None where not measured):
+    # ``seconds`` = dispatch_s (execute until the jitted call returns) +
+    # sync_s (block_until_ready); before it ran, gate_s (issue until its
+    # inputs settled) and queue_s (then until the worker took it)
+    dispatch_s: Optional[float] = None
+    sync_s: Optional[float] = None
+    gate_s: Optional[float] = None
+    queue_s: Optional[float] = None
 
 
 @dataclass
@@ -186,10 +194,14 @@ class CostModel:
                                      nbytes=int(nbytes), n_messages=n_messages))
 
     def record_compute(self, device: int, seconds: float, tag: str = "",
-                       kernel: str = "") -> None:
+                       kernel: str = "", *, dispatch_s: Optional[float] = None,
+                       sync_s: Optional[float] = None,
+                       gate_s: Optional[float] = None,
+                       queue_s: Optional[float] = None) -> None:
         with self._lock:
             self.compute.append(ComputeRecord(device, float(seconds), tag,
-                                              kernel))
+                                              kernel, dispatch_s, sync_s,
+                                              gate_s, queue_s))
             self.events.append(Event("compute", device, tag=tag,
                                      seconds=float(seconds)))
 

@@ -30,9 +30,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .costmodel import CostModel, LinkModel, PAPER_ETHERNET
 from .kernel_table import GLOBAL_KERNEL_TABLE, KernelTable
 from .mediary import HostMirror, MediaryStore, PresentTable
+from .spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -140,10 +142,6 @@ class NodeDevice:
             return None
         if cmd.op == "EXEC":
             entry = table.lookup(cmd.kernel_index)
-            fn = self._jit_cache.get(cmd.kernel_index)
-            if fn is None:
-                fn = jax.jit(entry.fn, static_argnames=payload.get("static_argnames", ()))
-                self._jit_cache[cmd.kernel_index] = fn
             # buffers: name -> handle, or name -> [handles] for pytree-valued
             # maps; the treedef travels in the EXEC message (paper §4.2: "the
             # host creates a struct in which it places the mediary address
@@ -164,7 +162,15 @@ class NodeDevice:
             params = inspect.signature(entry.fn).parameters
             if not any(p.kind == inspect.Parameter.VAR_KEYWORD for p in params.values()):
                 kwargs = {k: v for k, v in kwargs.items() if k in params}
-            return fn(**kwargs)
+            fn = self._jit_cache.get(cmd.kernel_index)
+            if fn is not None:
+                return fn(**kwargs)
+            # the module is named after the table entry (``jit_<name>``)
+            fn = jax.jit(entry.named_fn,
+                         static_argnames=payload.get("static_argnames", ()))
+            self._jit_cache[cmd.kernel_index] = fn
+            with span(spans.JIT_BUILD, kernel=entry.name, device=self.index):
+                return fn(**kwargs)
         if cmd.op == "STOP":
             self.stopped = True
             return None
@@ -308,13 +314,23 @@ class HealthRegistry:
 
 
 class _WorkItem:
-    """One enqueued command: a closure the device worker runs in order."""
+    """One enqueued command: a closure the device worker runs in order.
 
-    __slots__ = ("fn", "future")
+    ``cmd`` is the :class:`Command` served (None for the device-local
+    write-back of a kernel result).  ``issued`` is stamped when the item is
+    made, ``ready`` when the stream hands it to the worker's queue (its
+    dependencies settled); an EXEC's record reads both."""
 
-    def __init__(self, fn: Callable[[], Any], future: "_cf.Future") -> None:
+    __slots__ = ("fn", "future", "cmd", "op", "issued", "ready")
+
+    def __init__(self, fn: Callable[[], Any], future: "_cf.Future",
+                 cmd: Optional[Command] = None) -> None:
         self.fn = fn
         self.future = future
+        self.cmd = cmd
+        self.op = cmd.op if cmd is not None else "WRITEBACK"
+        self.issued = time.perf_counter()
+        self.ready = self.issued
 
 
 class StreamTicket:
@@ -430,14 +446,22 @@ class DevicePool:
     # -- the per-device command-queue worker ---------------------------------
     def _worker(self, device: int) -> None:
         q = self._queues[device]
+        traces = self.stream_traces[device]
         while True:
             item = q.get()
             if item is None:                 # sentinel: queue closed
                 return
-            try:
-                item.future.set_result(item.fn())
-            except BaseException as e:       # propagate to the issuer
-                item.future.set_exception(e)
+            tag = ""
+            if item.cmd is not None:
+                # execution (not issue) order; no lock: only this worker
+                # appends to stream_traces[device] (readers sync first)
+                traces.append(item.cmd)
+                tag = item.cmd.tag
+            with span(spans.CMD[item.op], device=device, tag=tag):
+                try:
+                    item.future.set_result(item.fn())
+                except BaseException as e:   # propagate to the issuer
+                    item.future.set_exception(e)
 
     def _stream_deps(self, device: int, fut: "_cf.Future",
                      reads: Sequence[int], writes: Sequence[int],
@@ -486,6 +510,7 @@ class DevicePool:
         stream, they do not gate on success (async failures surface at the
         next sync op, exactly as in the serial queue)."""
         if not deps:
+            item.ready = time.perf_counter()
             self._queues[device].put(item)
             return
         remaining = [len(deps)]
@@ -496,6 +521,7 @@ class DevicePool:
                 remaining[0] -= 1
                 if remaining[0]:
                     return
+            item.ready = time.perf_counter()
             self._queues[device].put(item)
 
         for f in deps:
@@ -503,7 +529,15 @@ class DevicePool:
 
     def _submit(self, device: int, fn: Callable[[], Any], *,
                 reads: Sequence[int] = (), writes: Sequence[int] = (),
-                extra_deps: Sequence["_cf.Future"] = ()) -> "_cf.Future":
+                extra_deps: Sequence["_cf.Future"] = (),
+                cmd: Optional[Command] = None) -> "_cf.Future":
+        return self._enqueue(device, fn, reads=reads, writes=writes,
+                             extra_deps=extra_deps, cmd=cmd).future
+
+    def _enqueue(self, device: int, fn: Callable[[], Any], *,
+                 reads: Sequence[int] = (), writes: Sequence[int] = (),
+                 extra_deps: Sequence["_cf.Future"] = (),
+                 cmd: Optional[Command] = None) -> _WorkItem:
         # stopped-check and registration are atomic under the issue lock so
         # no item can land behind stop_all's close sentinel (a worker that
         # already exited would leave the submitter blocked forever)
@@ -516,15 +550,17 @@ class DevicePool:
             if len(out) > 64:                # prune settled commands in place
                 out[:] = [f for f in out if not f.done()]
             out.append(fut)
-        self._gate(device, _WorkItem(fn, fut), deps)
-        return fut
+        item = _WorkItem(fn, fut, cmd)
+        self._gate(device, item, deps)
+        return item
 
     def _submit_async(self, device: int, fn: Callable[[], Any], *,
                       reads: Sequence[int] = (), writes: Sequence[int] = (),
-                      extra_deps: Sequence["_cf.Future"] = ()) -> "_cf.Future":
+                      extra_deps: Sequence["_cf.Future"] = (),
+                      cmd: Optional[Command] = None) -> "_cf.Future":
         """Enqueue fire-and-forget; failures surface at the next sync op."""
         fut = self._submit(device, fn, reads=reads, writes=writes,
-                           extra_deps=extra_deps)
+                           extra_deps=extra_deps, cmd=cmd)
 
         def _stash(f: "_cf.Future") -> None:
             err = f.exception()
@@ -581,19 +617,6 @@ class DevicePool:
         with self._trace_lock:
             self.failures_absorbed += len(absorbed)
         return absorbed
-
-    def _traced(self, device: int, cmd: Command,
-                fn: Callable[[], Any]) -> Callable[[], Any]:
-        """Wrap ``fn`` to log the command in execution (not issue) order.
-
-        No lock: only device ``d``'s single worker thread appends to
-        ``stream_traces[d]`` (readers synchronize via :meth:`sync`)."""
-
-        def run():
-            self.stream_traces[device].append(cmd)
-            return fn()
-
-        return run
 
     def open_reader(self, device: int, handles: Sequence[int]) -> StreamTicket:
         """Register a reader of ``handles`` ahead of the EXEC that uses them.
@@ -682,9 +705,8 @@ class DevicePool:
             payload = {"shape": tuple(shape), "dtype": dtype}
             self._submit_async(
                 device,
-                self._traced(device, cmd,
-                             lambda: self.devices[device].execute(cmd, self.table, payload)),
-                writes=cmd.writes)
+                lambda: self.devices[device].execute(cmd, self.table, payload),
+                writes=cmd.writes, cmd=cmd)
             return handle
 
     def free(self, device: int, handle: int) -> None:
@@ -695,9 +717,8 @@ class DevicePool:
             self._log(cmd)
             self._submit_async(
                 device,
-                self._traced(device, cmd,
-                             lambda: self.devices[device].execute(cmd, self.table)),
-                writes=cmd.writes)
+                lambda: self.devices[device].execute(cmd, self.table),
+                writes=cmd.writes, cmd=cmd)
 
     def transfer_to(self, device: int, handle: int, value: Any,
                     section: Optional[slice] = None, tag: str = "") -> "_cf.Future":
@@ -713,9 +734,8 @@ class DevicePool:
             payload = {"value": value, "section": section}
             return self._submit_async(
                 device,
-                self._traced(device, cmd,
-                             lambda: self.devices[device].execute(cmd, self.table, payload)),
-                writes=cmd.writes)
+                lambda: self.devices[device].execute(cmd, self.table, payload),
+                writes=cmd.writes, cmd=cmd)
 
     def transfer_from(self, device: int, handle: int,
                       section: Optional[slice] = None, tag: str = "") -> jax.Array:
@@ -726,10 +746,9 @@ class DevicePool:
             payload = {"section": section}
             fut = self._submit(
                 device,
-                self._traced(device, cmd,
-                             lambda: jax.block_until_ready(
-                                 self.devices[device].execute(cmd, self.table, payload))),
-                reads=cmd.reads)
+                lambda: jax.block_until_ready(
+                    self.devices[device].execute(cmd, self.table, payload)),
+                reads=cmd.reads, cmd=cmd)
         out = self._await_deadline(device, fut, cmd)
         self._raise_async(device)
         nbytes = out.size * out.dtype.itemsize
@@ -781,9 +800,8 @@ class DevicePool:
             self._log(scmd)
             send_fut = self._submit_async(
                 src,
-                self._traced(src, scmd,
-                             lambda: self.devices[src].execute(scmd, self.table)),
-                reads=scmd.reads)
+                lambda: self.devices[src].execute(scmd, self.table),
+                reads=scmd.reads, cmd=scmd)
         with self.locks[dst]:
             rcmd = Command("RECV", dst, handle=dst_handle, nbytes=wire,
                            tag=tag, peer=src, writes=(dst_handle,))
@@ -791,10 +809,8 @@ class DevicePool:
             payload = {"source": send_fut}
             recv_fut = self._submit_async(
                 dst,
-                self._traced(dst, rcmd,
-                             lambda: self.devices[dst].execute(rcmd, self.table,
-                                                               payload)),
-                writes=rcmd.writes, extra_deps=(send_fut,))
+                lambda: self.devices[dst].execute(rcmd, self.table, payload),
+                writes=rcmd.writes, extra_deps=(send_fut,), cmd=rcmd)
         self.cost.record_peer(src, dst, wire, tag=tag)
         return recv_fut
 
@@ -828,16 +844,24 @@ class DevicePool:
 
             def run_exec():
                 t0 = time.perf_counter()
-                out = self.devices[device].execute(cmd, self.table, payload)
-                out = jax.block_until_ready(out)
-                return out, time.perf_counter() - t0
+                with span(spans.EXEC_DISPATCH):
+                    out = self.devices[device].execute(cmd, self.table, payload)
+                t1 = time.perf_counter()
+                with span(spans.EXEC_SYNC):
+                    out = jax.block_until_ready(out)
+                t2 = time.perf_counter()
+                return out, t0, t1, t2
 
-            fut = self._submit(device, self._traced(device, cmd, run_exec),
-                               reads=reads, extra_deps=extra_deps)
-        out, seconds = self._await_deadline(device, fut, cmd)
+            item = self._enqueue(device, run_exec, reads=reads,
+                                 extra_deps=extra_deps, cmd=cmd)
+        with span(spans.REGION_EXEC_WAIT):
+            out, t0, t1, t2 = self._await_deadline(device, item.future, cmd)
         self._raise_async(device)
-        self.cost.record_compute(device, seconds, tag=tag or kernel_name,
-                                 kernel=kernel_name)
+        self.cost.record_compute(device, t2 - t0, tag=tag or kernel_name,
+                                 kernel=kernel_name, dispatch_s=t1 - t0,
+                                 sync_s=t2 - t1,
+                                 gate_s=item.ready - item.issued,
+                                 queue_s=t0 - item.ready)
         return out
 
     def _stop_device(self, i: int) -> Optional["_cf.Future"]:
@@ -856,9 +880,8 @@ class DevicePool:
             self._outstanding[i].append(fut)
             self._stopped[i] = True
         self._gate(i, _WorkItem(
-            self._traced(i, cmd,
-                         lambda i=i, cmd=cmd: self.devices[i].execute(cmd, self.table)),
-            fut), deps)
+            lambda i=i, cmd=cmd: self.devices[i].execute(cmd, self.table),
+            fut, cmd), deps)
         # worker exits once STOP has executed; nothing can trail it
         # (every earlier command is a dependency of STOP, and the
         # stopped flag refuses new submissions)

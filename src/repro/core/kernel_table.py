@@ -14,6 +14,7 @@ device-side command loop of paper §4.1, expressed as traced control flow).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -34,6 +35,25 @@ class KernelEntry:
     # without the caller supplying operand shapes.  Excluded from
     # fingerprint(): it is measurement metadata, not dispatch identity.
     example: Optional[Callable] = None
+    # ``fn`` under the entry's name: jit names its XLA module after the
+    # function it wraps, so a device trace reads ``jit_<name>`` whether
+    # ``fn`` is a lambda or a function of another name.  One per entry, so
+    # every device's jit wrapper shares one trace.
+    named_fn: Callable = field(init=False, repr=False, compare=False)
+
+    def __post_init__(self) -> None:
+        object.__setattr__(self, "named_fn", _renamed(self.fn, self.name))
+
+
+def _renamed(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``, keeping its signature (``__wrapped__``)."""
+
+    @functools.wraps(fn)
+    def kernel(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    kernel.__name__ = kernel.__qualname__ = name
+    return kernel
 
 
 class KernelTable:

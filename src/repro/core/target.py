@@ -58,6 +58,7 @@ import numpy as np
 from .device import (DeviceFailure, DevicePool, DeviceStoppedError,
                      StreamTicket, host_array)
 from .mediary import PresentEntry, same_treedef
+from .spans import REGION, span
 
 
 @dataclass(frozen=True)
@@ -729,6 +730,10 @@ class TargetExecutor:
 
     # -- region lifecycle (paper §4.1/§4.2) ------------------------------------
     def _run(self, kernel: str, device: int, maps: MapSpec, tag: str) -> Dict[str, jax.Array]:
+        with span(REGION, device=device, kernel=kernel, tag=tag):
+            return self._region(kernel, device, maps, tag)
+
+    def _region(self, kernel: str, device: int, maps: MapSpec, tag: str) -> Dict[str, jax.Array]:
         pool = self.pool
         handles: Dict[str, Any] = {}   # name -> handle | [handles] (pytree)
         trees: Dict[str, Any] = {}     # name -> treedef for pytree maps

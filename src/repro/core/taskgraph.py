@@ -48,7 +48,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .device import DeviceFailure
+from .spans import span
 from .target import (MapSpec, Section, TargetExecutor, TargetFuture,
                      _alias_map, _flatten_map_value)
 from .transport import HostFunnelTransport
@@ -1241,11 +1243,9 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
             else:
                 results[name] = value
 
-    # the topological decomposition is the graph's own (one wave drains
-    # fully before the next is planned, so ready == waves()); cycles and
-    # missing deps surface here, before anything is dispatched
-    waves = graph.waves()
-    for wave_idx, wave in enumerate(waves):
+    def _run_wave(wave_idx: int, wave: List[str]) -> None:
+        """One wave: plan, issue and join its regions; pins released at
+        its end."""
         ready = [graph.node(n) for n in wave if n not in completed]
         ctx.wave = wave_idx
         # wave boundary: advance blacklist probation (a clean wave accrues
@@ -1261,72 +1261,75 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
         records: List[Dict[str, Any]] = []
         joined = False
         try:
-            plans: List[Dict[str, Any]] = []
-            for j, t in enumerate(ready):
-                region_tag = t.tag or f"{tag}:w{wave_idx}:{t.name}"
-                dev = policy.place(ctx, t, j, region_tag)
-                if not (0 <= dev < D):
-                    raise ValueError(
-                        f"policy {policy.name!r} placed {t.name!r} on "
-                        f"device {dev} of {D}")
-                ctx.load[dev] = ctx.load.get(dev, 0) + 1
-                ctx.home[t.name] = dev
-                ctx.replicas.setdefault(t.name, set()).add(dev)
-                orig_maps = t.make_maps({d: results[d] for d in t.deps})
-                maps = (_peer_rewrite(t, dev, orig_maps, region_tag)
-                        if peer else orig_maps)
-                plans.append({"t": t, "dev": dev, "tag": region_tag,
-                              "maps": maps, "orig_maps": orig_maps,
-                              "index": j, "attempts": 0, "out": None})
-            if resident:
-                # pin only values genuinely shared: a (device, name) whose
-                # plain to/tofrom value is identical across >=2 of the wave's
-                # tasks.  Pinning per-task-varying values would gain nothing
-                # and each refresh could race an in-flight sibling region out
-                # of its elision (value-correct either way, but the byte
-                # savings would depend on thread scheduling).
-                usage: Dict[Tuple[int, str], List[Tuple[Tuple[int, ...], Any]]] = {}
+            with span(spans.WAVE_PLAN):
+                plans: List[Dict[str, Any]] = []
+                for j, t in enumerate(ready):
+                    region_tag = t.tag or f"{tag}:w{wave_idx}:{t.name}"
+                    dev = policy.place(ctx, t, j, region_tag)
+                    if not (0 <= dev < D):
+                        raise ValueError(
+                            f"policy {policy.name!r} placed {t.name!r} on "
+                            f"device {dev} of {D}")
+                    ctx.load[dev] = ctx.load.get(dev, 0) + 1
+                    ctx.home[t.name] = dev
+                    ctx.replicas.setdefault(t.name, set()).add(dev)
+                    orig_maps = t.make_maps({d: results[d] for d in t.deps})
+                    maps = (_peer_rewrite(t, dev, orig_maps, region_tag)
+                            if peer else orig_maps)
+                    plans.append({"t": t, "dev": dev, "tag": region_tag,
+                                  "maps": maps, "orig_maps": orig_maps,
+                                  "index": j, "attempts": 0, "out": None})
+                if resident:
+                    # pin only values genuinely shared: a (device, name) whose
+                    # plain to/tofrom value is identical across >=2 of the wave's
+                    # tasks.  Pinning per-task-varying values would gain nothing
+                    # and each refresh could race an in-flight sibling region out
+                    # of its elision (value-correct either way, but the byte
+                    # savings would depend on thread scheduling).
+                    usage: Dict[Tuple[int, str], List[Tuple[Tuple[int, ...], Any]]] = {}
+                    for p in plans:
+                        dev, maps = p["dev"], p["maps"]
+                        # to-maps only: tofrom buffers are written back per task,
+                        # and two regions sharing one pinned output handle would
+                        # fetch each other's results
+                        for n, v in maps.to.items():
+                            leaves, _ = _flatten_map_value(v)
+                            if any(isinstance(l, Section) for l in leaves):
+                                continue   # sections differ per task: not pinnable
+                            usage.setdefault((dev, n), []).append(
+                                (tuple(id(l) for l in leaves), v))
+                    for (dev, n), uses in usage.items():
+                        if len(uses) < 2 or len({k for k, _ in uses}) != 1:
+                            continue       # unique or conflicting values: no pin
+                        try:
+                            ex.enter_data(dev, f"{tag}:w{wave_idx}", **{n: uses[0][1]})
+                            entered.append((dev, n))
+                        except ValueError:
+                            pass           # shape changed under this name: skip pin
+            with span(spans.WAVE_ISSUE):
                 for p in plans:
-                    dev, maps = p["dev"], p["maps"]
-                    # to-maps only: tofrom buffers are written back per task,
-                    # and two regions sharing one pinned output handle would
-                    # fetch each other's results
-                    for n, v in maps.to.items():
-                        leaves, _ = _flatten_map_value(v)
-                        if any(isinstance(l, Section) for l in leaves):
-                            continue   # sections differ per task: not pinnable
-                        usage.setdefault((dev, n), []).append(
-                            (tuple(id(l) for l in leaves), v))
-                for (dev, n), uses in usage.items():
-                    if len(uses) < 2 or len({k for k, _ in uses}) != 1:
-                        continue       # unique or conflicting values: no pin
-                    try:
-                        ex.enter_data(dev, f"{tag}:w{wave_idx}", **{n: uses[0][1]})
-                        entered.append((dev, n))
-                    except ValueError:
-                        pass           # shape changed under this name: skip pin
-            for p in plans:
-                t = p["t"]
-                if nowait:
-                    p["start"] = time.monotonic()
-                    p["fut"] = ex.target(t.kernel, p["dev"], p["maps"],
-                                         nowait=True, tag=p["tag"])
-                    futs.append(p["fut"])
-                    records.append(p)
-                else:
-                    out = _run_recovering(p)
-                    results[t.name] = (PeerRef(t.name, producer[t.name][1],
-                                               producer[t.name][0])
-                                       if peer else out[out_name])
-                    if not peer:
-                        ctx.out_bytes[t.name] = _value_nbytes(results[t.name])
+                    t = p["t"]
+                    if nowait:
+                        p["start"] = time.monotonic()
+                        p["fut"] = ex.target(t.kernel, p["dev"], p["maps"],
+                                             nowait=True, tag=p["tag"])
+                        futs.append(p["fut"])
+                        records.append(p)
+                    else:
+                        out = _run_recovering(p)
+                        results[t.name] = (PeerRef(t.name, producer[t.name][1],
+                                                   producer[t.name][0])
+                                           if peer else out[out_name])
+                        if not peer:
+                            ctx.out_bytes[t.name] = _value_nbytes(results[t.name])
             if records:
                 # the join waits for EVERY region to settle (even past a
                 # failure, even across re-dispatches), so the pin release
                 # below can never pull a buffer out from under a
                 # still-running region
                 joined = True
-                _join_recovering(records)
+                with span(spans.WAVE_JOIN):
+                    _join_recovering(records)
                 for p in records:
                     t = p["t"]
                     results[t.name] = (PeerRef(t.name, producer[t.name][1],
@@ -1365,15 +1368,30 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
             for dev, n in entered:      # wave boundary: release pins
                 if dev < len(pool):
                     ex.exit_data(dev, n)
-    if peer:
-        # materialize the host view — one fetch per task output, exactly
-        # what the host-mediated run's from_ maps moved — then release
-        # every entry this run pinned (outputs and propagated peer copies)
-        try:
-            for name in list(producer):
-                results[name] = _fetch_task(name)
-        finally:
-            for dev, n in peer_entries:
-                if dev < len(pool):
-                    ex.exit_data(dev, n)
-    return results
+
+    # the topological decomposition is the graph's own (one wave drains
+    # fully before the next is planned, so ready == waves()); cycles and
+    # missing deps surface here, before anything is dispatched
+    waves = graph.waves()
+    try:
+        for wave_idx, wave in enumerate(waves):
+            with span(spans.WAVE, wave=wave_idx):
+                _run_wave(wave_idx, wave)
+        if peer:
+            # materialize the host view — one fetch per task output, exactly
+            # what the host-mediated run's from_ maps moved — then release
+            # every entry this run pinned (outputs and propagated peer copies)
+            try:
+                for name in list(producer):
+                    results[name] = _fetch_task(name)
+            finally:
+                for dev, n in peer_entries:
+                    if dev < len(pool):
+                        ex.exit_data(dev, n)
+        return results
+    finally:
+        # _replay_producer and _peer_rewrite call each other, so their
+        # closures form a reference cycle that holds ``results`` and every
+        # array of the run until the cyclic collector runs (device memory
+        # of many finished runs at once); unbinding one breaks the cycle
+        _peer_rewrite = None

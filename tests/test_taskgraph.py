@@ -534,3 +534,37 @@ def test_placement_report_predicted_vs_observed(sparselu):
             assert rt.cost.kernel_time(kernel) > 0.0
     finally:
         rt.shutdown()
+
+
+def test_a_finished_run_leaves_no_cycle_holding_its_arrays():
+    """``run_graph``'s helpers call each other; once it returns, its
+    results and device arrays go by reference counting, not at the next
+    cyclic collection (which would keep many runs' device memory alive)."""
+    import gc
+    table = KernelTable()
+    table.register("inc", lambda x: {"out": x + 1.0})
+    rt = ClusterRuntime(RuntimeConfig(n_virtual=2, comm_mode="direct"),
+                        table=table)
+    x = jnp.ones((8,), jnp.float32)
+    sds = jax.ShapeDtypeStruct((8,), jnp.float32)
+    tasks = [DagTask("a", "inc", (), lambda r: MapSpec(to={"x": x},
+                                                       from_={"out": sds})),
+             DagTask("b", "inc", ("a",), lambda r: MapSpec(
+                 to={"x": r["a"]}, from_={"out": sds}))]
+    for peer in (False, True):
+        rt.wavefront_offload(tasks, nowait=True, peer=peer)   # warm
+        gc.collect()
+        gc.disable()
+        try:
+            res = rt.wavefront_offload(tasks, nowait=True, peer=peer)
+            np.testing.assert_allclose(res["b"], np.full(8, 3.0))
+            del res
+            gc.set_debug(gc.DEBUG_SAVEALL)
+            gc.collect()
+            arrays = [o for o in gc.garbage if isinstance(o, jax.Array)]
+        finally:
+            gc.set_debug(0)
+            gc.garbage.clear()
+            gc.enable()
+        assert arrays == []
+    rt.shutdown()
