@@ -74,11 +74,13 @@ class ComputeRecord:
     # an EXEC's parts, on its worker's clock (None where not measured):
     # ``seconds`` = dispatch_s (execute until the jitted call returns) +
     # sync_s (block_until_ready); before it ran, gate_s (issue until its
-    # inputs settled) and queue_s (then until the worker took it)
+    # inputs settled) and queue_s (then until the worker took it);
+    # zero_fills: unwritten slots its argument resolution zero-filled
     dispatch_s: Optional[float] = None
     sync_s: Optional[float] = None
     gate_s: Optional[float] = None
     queue_s: Optional[float] = None
+    zero_fills: Optional[int] = None
 
 
 @dataclass
@@ -197,11 +199,12 @@ class CostModel:
                        kernel: str = "", *, dispatch_s: Optional[float] = None,
                        sync_s: Optional[float] = None,
                        gate_s: Optional[float] = None,
-                       queue_s: Optional[float] = None) -> None:
+                       queue_s: Optional[float] = None,
+                       zero_fills: Optional[int] = None) -> None:
         with self._lock:
             self.compute.append(ComputeRecord(device, float(seconds), tag,
                                               kernel, dispatch_s, sync_s,
-                                              gate_s, queue_s))
+                                              gate_s, queue_s, zero_fills))
             self.events.append(Event("compute", device, tag=tag,
                                      seconds=float(seconds)))
 

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures as _cf
-import inspect
 import queue
 import threading
 import time
@@ -146,22 +145,28 @@ class NodeDevice:
             # maps; the treedef travels in the EXEC message (paper §4.2: "the
             # host creates a struct in which it places the mediary address
             # for each variable ... and sends the struct to the device").
+            # OpenMP kernels mutate mapped buffers in place; JAX kernels are
+            # functional, so a kernel only *receives* the mapped names it
+            # declares as parameters (a pure-``from`` output buffer need not
+            # be an input) and *returns* the from/tofrom values.  A name it
+            # does not take is never read, so an unwritten output slot stays
+            # an unfilled calloc until the write-back replaces it; its
+            # handles must still be live.
+            takes = entry.params
             trees = payload.get("trees", {})
             kwargs = {}
             for name, h in payload["buffers"].items():
-                if isinstance(h, (list, tuple)):
+                if takes is not None and name not in takes:
+                    for x in (h if isinstance(h, (list, tuple)) else (h,)):
+                        self.store._live(x)
+                elif isinstance(h, (list, tuple)):
                     leaves = [self.store.device_address(x) for x in h]
                     kwargs[name] = jax.tree.unflatten(trees[name], leaves)
                 else:
                     kwargs[name] = self.store.device_address(h)
-            kwargs.update(payload.get("firstprivate", {}))
-            # OpenMP kernels mutate mapped buffers in place; JAX kernels are
-            # functional, so a kernel only *receives* the mapped names it
-            # declares as parameters (a pure-``from`` output buffer need not
-            # be an input) and *returns* the from/tofrom values.
-            params = inspect.signature(entry.fn).parameters
-            if not any(p.kind == inspect.Parameter.VAR_KEYWORD for p in params.values()):
-                kwargs = {k: v for k, v in kwargs.items() if k in params}
+            for name, v in payload.get("firstprivate", {}).items():
+                if takes is None or name in takes:
+                    kwargs[name] = v
             fn = self._jit_cache.get(cmd.kernel_index)
             if fn is not None:
                 return fn(**kwargs)
@@ -843,25 +848,28 @@ class DevicePool:
                        "static_argnames": tuple(static_argnames)}
 
             def run_exec():
+                dev = self.devices[device]
+                fills = dev.store.zero_fills
                 t0 = time.perf_counter()
                 with span(spans.EXEC_DISPATCH):
-                    out = self.devices[device].execute(cmd, self.table, payload)
+                    out = dev.execute(cmd, self.table, payload)
                 t1 = time.perf_counter()
                 with span(spans.EXEC_SYNC):
                     out = jax.block_until_ready(out)
                 t2 = time.perf_counter()
-                return out, t0, t1, t2
+                return out, t0, t1, t2, dev.store.zero_fills - fills
 
             item = self._enqueue(device, run_exec, reads=reads,
                                  extra_deps=extra_deps, cmd=cmd)
         with span(spans.REGION_EXEC_WAIT):
-            out, t0, t1, t2 = self._await_deadline(device, item.future, cmd)
+            out, t0, t1, t2, fills = self._await_deadline(device,
+                                                          item.future, cmd)
         self._raise_async(device)
         self.cost.record_compute(device, t2 - t0, tag=tag or kernel_name,
                                  kernel=kernel_name, dispatch_s=t1 - t0,
                                  sync_s=t2 - t1,
                                  gate_s=item.ready - item.issued,
-                                 queue_s=t0 - item.ready)
+                                 queue_s=t0 - item.ready, zero_fills=fills)
         return out
 
     def _stop_device(self, i: int) -> Optional["_cf.Future"]:

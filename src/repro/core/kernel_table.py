@@ -16,8 +16,9 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import inspect
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, FrozenSet, List, Optional, Tuple
 
 import jax
 
@@ -40,9 +41,22 @@ class KernelEntry:
     # ``fn`` is a lambda or a function of another name.  One per entry, so
     # every device's jit wrapper shares one trace.
     named_fn: Callable = field(init=False, repr=False, compare=False)
+    # the mapped names ``fn`` takes, read once at registration; None when
+    # it takes ``**kwargs`` (every name).  An EXEC resolves only these.
+    params: Optional[FrozenSet[str]] = field(init=False, repr=False,
+                                             compare=False)
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "named_fn", _renamed(self.fn, self.name))
+        object.__setattr__(self, "params", _params(self.fn))
+
+
+def _params(fn: Callable) -> Optional[FrozenSet[str]]:
+    """The names ``fn`` takes as keywords, or None if it takes ``**kwargs``."""
+    params = inspect.signature(fn).parameters
+    if any(p.kind == inspect.Parameter.VAR_KEYWORD for p in params.values()):
+        return None
+    return frozenset(params)
 
 
 def _renamed(fn: Callable, name: str) -> Callable:

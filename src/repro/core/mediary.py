@@ -77,6 +77,7 @@ class MediaryStore(SlotTableBase):
     def __init__(self, sharding: Optional[jax.sharding.Sharding] = None) -> None:
         super().__init__()
         self._sharding = sharding
+        self.zero_fills = 0      # unwritten slots made zeros by a read
 
     # -- commands from the host (paper §4.1 command types) -----------------
     def alloc(self, shape: Sequence[int], dtype: Any) -> int:
@@ -123,6 +124,7 @@ class MediaryStore(SlotTableBase):
         if isinstance(cur, _Calloc):     # first read of an unwritten slot
             cur = self._slots[handle] = jnp.zeros(cur.shape, cur.dtype,
                                                   device=self._sharding)
+            self.zero_fills += 1
         return cur
 
     # Device addresses (paper fig. 1 right column) — for tracing/debugging.

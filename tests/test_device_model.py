@@ -1,5 +1,8 @@
 """Unit + property tests for the paper's core mechanisms (§4):
 kernel table, mediary addresses, map semantics, command protocol."""
+import gc
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +12,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import (DevicePool, HostMirror, KernelTable, MapSpec,
                         MediaryStore, TargetExecutor, sec)
 from repro.core.device import Command
+from repro.core.mediary import _Calloc
 
 
 # ---------------------------------------------------------------------------
@@ -208,3 +212,116 @@ def test_config_file_multiplier():
     pool = DevicePool.from_config(["node0 2", "node1", "# comment"])
     assert len(pool) == 3
     assert [d.hostname for d in pool.devices] == ["node0", "node0", "node1"]
+
+
+# ---------------------------------------------------------------------------
+# EXEC argument resolution: only the buffers a kernel takes are read
+# ---------------------------------------------------------------------------
+def _resolution_table(seen=None):
+    t = KernelTable()
+    t.register("ignores_out", lambda a: {"out": a + 1.0})
+    t.register("reads_out", lambda a, out: {"out": a + out})
+
+    def takes_all(**kw):
+        if seen is not None:
+            seen.append(sorted(kw))          # at trace time: the names given
+        return {"out": kw["a"] * kw["alpha"] + kw["out"]}
+
+    t.register("takes_all", takes_all)
+    return t
+
+
+def _exec_with_output_slot(pool, kernel, **kw):
+    """EXEC ``kernel`` on device 0 with ``a`` written and ``out`` only
+    ALLOC'd, as a ``from`` map leaves it; returns (result, out handle)."""
+    a = pool.alloc(0, (4,), jnp.float32)
+    pool.transfer_to(0, a, np.arange(4, dtype=np.float32))
+    out = pool.alloc(0, (4,), jnp.float32)
+    res = pool.exec_kernel(0, kernel, buffers={"a": a, "out": out}, **kw)
+    pool.sync()
+    return res, out
+
+
+@pytest.mark.parametrize("kernel, fills, names, want", [
+    # (a) the output slot is not taken: never read, never filled
+    ("ignores_out", 0, None, np.arange(4.0) + 1.0),
+    # (b) a kernel that takes its ALLOC'd output reads OMPi's calloc zeros
+    ("reads_out", 1, None, np.arange(4.0)),
+    # (c) a **kwargs kernel receives every mapped and firstprivate name
+    ("takes_all", 1, ["a", "alpha", "out"], 2.0 * np.arange(4.0)),
+])
+def test_exec_resolves_only_the_buffers_its_kernel_takes(kernel, fills,
+                                                         names, want):
+    seen = []
+    pool = DevicePool.virtual(1, table=_resolution_table(seen))
+    store = pool.devices[0].store
+    res, out = _exec_with_output_slot(pool, kernel,
+                                      firstprivate={"alpha": 2.0})
+    np.testing.assert_array_equal(res["out"], want)
+    assert pool.cost.compute[-1].zero_fills == fills
+    assert store.zero_fills == fills
+    assert isinstance(store._slots[out], _Calloc) == (fills == 0)
+    if names is not None:
+        assert seen == [names]
+    # the write-back installs the result without filling the slot first
+    pool.transfer_to_writeback(0, out, res["out"]).result()
+    np.testing.assert_array_equal(store.read(out), want)
+    assert store.zero_fills == fills
+    pool.stop_all()
+
+
+@pytest.mark.parametrize("kernel", ["ignores_out", "reads_out"])
+def test_exec_mapping_a_freed_handle_raises_whether_taken_or_not(kernel):
+    """(d) a dead mapped handle still raises, even where the kernel does
+    not take it and so nothing reads it."""
+    pool = DevicePool.virtual(1, table=_resolution_table())
+    a = pool.alloc(0, (4,), jnp.float32)
+    pool.transfer_to(0, a, np.ones(4, np.float32))
+    out = pool.alloc(0, (4,), jnp.float32)
+    pool.free(0, out)
+    with pytest.raises(KeyError, match="not live"):
+        pool.exec_kernel(0, kernel, buffers={"a": a, "out": out})
+    pool.stop_all()
+
+
+def test_tables_reusing_a_name_keep_each_kernels_own_parameters():
+    """(e) two tables, one after the other, register different functions
+    under one name: each EXEC resolves by its own table's entry (a filter
+    keyed on ``id(fn)`` would mix them up once an id is reused)."""
+    for make, params, want, fills in (
+            (lambda: lambda a: {"out": a + 1.0}, {"a"}, 1.0, 0),
+            (lambda: lambda a, out: {"out": a + out}, {"a", "out"}, 0.0, 1),
+            (lambda: lambda a: {"out": a + 1.0}, {"a"}, 1.0, 0)):
+        t = KernelTable()
+        t.register("k", make())
+        assert t.lookup(0).params == frozenset(params)
+        pool = DevicePool.virtual(1, table=t)
+        res, _ = _exec_with_output_slot(pool, "k")
+        np.testing.assert_array_equal(res["out"], np.arange(4.0) + want)
+        assert pool.cost.compute[-1].zero_fills == fills
+        pool.stop_all()
+        del t, pool, res
+        gc.collect()
+
+
+def test_kernel_signature_is_read_at_registration_not_per_exec(monkeypatch):
+    """(f) the parameter set is fixed when the kernel is registered; no
+    EXEC reads a signature again."""
+    t = _resolution_table()
+    assert t.lookup(t.index_of("ignores_out")).params == frozenset({"a"})
+    assert t.lookup(t.index_of("takes_all")).params is None
+    pool = DevicePool.virtual(1, table=t)
+    _exec_with_output_slot(pool, "ignores_out")       # builds the jit
+    reads = []
+    real = inspect.signature
+
+    def counting(obj, *args, **kwargs):
+        reads.append(obj)
+        return real(obj, *args, **kwargs)
+
+    monkeypatch.setattr(inspect, "signature", counting)
+    for _ in range(3):
+        res, _ = _exec_with_output_slot(pool, "ignores_out")
+    np.testing.assert_array_equal(res["out"], np.arange(4.0) + 1.0)
+    assert reads == []
+    pool.stop_all()
