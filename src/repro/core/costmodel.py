@@ -75,12 +75,15 @@ class ComputeRecord:
     # ``seconds`` = dispatch_s (execute until the jitted call returns) +
     # sync_s (block_until_ready); before it ran, gate_s (issue until its
     # inputs settled) and queue_s (then until the worker took it);
-    # zero_fills: unwritten slots its argument resolution zero-filled
+    # zero_fills: unwritten slots its argument resolution zero-filled;
+    # work: what the task declared it computes (``TaskNode.work``), None
+    # where it declared nothing
     dispatch_s: Optional[float] = None
     sync_s: Optional[float] = None
     gate_s: Optional[float] = None
     queue_s: Optional[float] = None
     zero_fills: Optional[int] = None
+    work: Optional[Dict[str, float]] = None
 
 
 @dataclass
@@ -200,11 +203,13 @@ class CostModel:
                        sync_s: Optional[float] = None,
                        gate_s: Optional[float] = None,
                        queue_s: Optional[float] = None,
-                       zero_fills: Optional[int] = None) -> None:
+                       zero_fills: Optional[int] = None,
+                       work: Optional[Dict[str, float]] = None) -> None:
         with self._lock:
             self.compute.append(ComputeRecord(device, float(seconds), tag,
                                               kernel, dispatch_s, sync_s,
-                                              gate_s, queue_s, zero_fills))
+                                              gate_s, queue_s, zero_fills,
+                                              work))
             self.events.append(Event("compute", device, tag=tag,
                                      seconds=float(seconds)))
 

@@ -95,6 +95,10 @@ class NodeDevice:
         self.store = MediaryStore(sharding=sharding)
         # where this device's buffers live: its sharding, else its jax device
         self.placement = sharding if sharding is not None else jax_device
+        # a device that is one chip of several runs its kernels there, even
+        # a kernel whose arguments are all host scalars
+        self._pinned = (jax_device is not None and isinstance(
+            sharding, jax.sharding.SingleDeviceSharding))
         self.stopped = False
         # resident-memory budget for this device's present table (None =
         # unbounded); enforced by the executor's LRU spill path, not here
@@ -107,6 +111,12 @@ class NodeDevice:
         if self.placement is None:
             return value
         return jax.device_put(value, self.placement)
+
+    def _call(self, fn: Callable, kwargs: Dict[str, Any]) -> Any:
+        if self._pinned:
+            with jax.default_device(self.jax_device):
+                return fn(**kwargs)
+        return fn(**kwargs)
 
     # -- the device-side command loop (paper §4.1) --------------------------
     def execute(self, cmd: Command, table: KernelTable,
@@ -168,14 +178,14 @@ class NodeDevice:
                 if takes is None or name in takes:
                     kwargs[name] = v
             fn = self._jit_cache.get(cmd.kernel_index)
-            if fn is not None:
-                return fn(**kwargs)
-            # the module is named after the table entry (``jit_<name>``)
-            fn = jax.jit(entry.named_fn,
-                         static_argnames=payload.get("static_argnames", ()))
-            self._jit_cache[cmd.kernel_index] = fn
-            with span(spans.JIT_BUILD, kernel=entry.name, device=self.index):
-                return fn(**kwargs)
+            if fn is None:
+                # the module is named after the table entry (``jit_<name>``)
+                fn = jax.jit(entry.named_fn,
+                             static_argnames=payload.get("static_argnames", ()))
+                self._jit_cache[cmd.kernel_index] = fn
+                with span(spans.JIT_BUILD, kernel=entry.name, device=self.index):
+                    return self._call(fn, kwargs)
+            return self._call(fn, kwargs)
         if cmd.op == "STOP":
             self.stopped = True
             return None
@@ -687,6 +697,15 @@ class DevicePool:
         devs = _np.moveaxis(mesh.devices, mesh.axis_names.index(axis), 0)
         out = []
         for i in range(devs.shape[0]):
+            chips = _np.array(devs[i], dtype=object).reshape(-1)
+            if chips.size == 1:
+                # one chip: its buffers and its kernels live there
+                dev = chips[0]
+                out.append(NodeDevice(
+                    i, jax_device=dev,
+                    sharding=jax.sharding.SingleDeviceSharding(dev),
+                    hostname=f"slice{i}"))
+                continue
             sub = jax.sharding.Mesh(devs[i], tuple(a for a in mesh.axis_names if a != axis))
             sharding = jax.sharding.NamedSharding(sub, jax.sharding.PartitionSpec())
             out.append(NodeDevice(i, sharding=sharding, hostname=f"slice{i}"))
@@ -825,13 +844,16 @@ class DevicePool:
                     trees: Optional[Dict[str, Any]] = None,
                     static_argnames: Sequence[str] = (), tag: str = "",
                     skip_reads: Sequence[int] = (),
-                    extra_deps: Sequence["_cf.Future"] = ()) -> Any:
+                    extra_deps: Sequence["_cf.Future"] = (),
+                    work: Optional[Dict[str, float]] = None) -> Any:
         """Run a kernel; reads are derived from the mapped buffer handles.
 
         ``skip_reads`` names handles an open :class:`StreamTicket` already
         covers — registering them again would deadlock on a writer that is
         itself waiting on the ticket; their ordering arrives via
         ``extra_deps`` (the ticket's captured last-writer futures) instead.
+        ``work`` is what the caller declares the kernel computes; it goes
+        onto the EXEC's compute record as given.
         """
         index = self.table.index_of(kernel_name)   # name → wire integer
         all_handles: List[int] = []
@@ -869,7 +891,8 @@ class DevicePool:
                                  kernel=kernel_name, dispatch_s=t1 - t0,
                                  sync_s=t2 - t1,
                                  gate_s=item.ready - item.issued,
-                                 queue_s=t0 - item.ready, zero_fills=fills)
+                                 queue_s=t0 - item.ready, zero_fills=fills,
+                                 work=work)
         return out
 
     def _stop_device(self, i: int) -> Optional["_cf.Future"]:

@@ -98,8 +98,7 @@ def offload_strips(ex: TargetExecutor, kernel: str, total: int,
         # parallel makespan (devices modeled concurrent).
         res = run_graph(ex, TaskGraph(nodes), policy=policy,
                         out_name=out_name, nowait=nowait, tag=tag)
-        return jnp.concatenate([res[n.name] for n in nodes],
-                               axis=combine_axis)
+        return _gather([res[n.name] for n in nodes], combine_axis)
     pol = resolve_policy(policy)
     D = len(ex.pool)
     ctx = PlacementContext(pool=ex.pool, cost=ex.pool.cost, D=D)
@@ -123,8 +122,15 @@ def offload_strips(ex: TargetExecutor, kernel: str, total: int,
         # unregistered either way (they are settled or abandoned here)
         ex.retire(futs)
         ex.retire(list(respawned.values()))
-    parts = [r[out_name] for r in results]
-    return jnp.concatenate(parts, axis=combine_axis)
+    return _gather([r[out_name] for r in results], combine_axis)
+
+
+def _gather(parts: List[jax.Array], axis: int) -> jax.Array:
+    """The strips joined along ``axis`` where the first one lies: strips
+    computed on other chips are copied there first."""
+    home = parts[0].sharding
+    return jnp.concatenate([jax.device_put(p, home) for p in parts],
+                           axis=axis)
 
 
 def _speculative_harvest(ex: TargetExecutor, kernel: str,

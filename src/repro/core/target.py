@@ -162,13 +162,19 @@ class TargetExecutor:
 
     # -- the target construct -------------------------------------------------
     def target(self, kernel: str, device: int, maps: MapSpec, *,
-               nowait: bool = False, tag: str = "") -> Union[Dict[str, jax.Array], TargetFuture]:
+               nowait: bool = False, tag: str = "",
+               work: Optional[Dict[str, float]] = None
+               ) -> Union[Dict[str, jax.Array], TargetFuture]:
+        """Run one region.  ``work`` is what the caller declares its kernel
+        computes (``TaskNode.work``); it goes onto the EXEC's compute
+        record."""
         if nowait:
-            fut = TargetFuture(self._tp.submit(self._run, kernel, device, maps, tag))
+            fut = TargetFuture(self._tp.submit(self._run, kernel, device,
+                                               maps, tag, work))
             with self._inflight_lock:
                 self._inflight.append(fut)
             return fut
-        return self._run(kernel, device, maps, tag)
+        return self._run(kernel, device, maps, tag, work)
 
     def taskwait(self) -> List[Dict[str, jax.Array]]:
         with self._inflight_lock:
@@ -729,11 +735,13 @@ class TargetExecutor:
         return fetched[0] if treedef is None else jax.tree.unflatten(treedef, fetched)
 
     # -- region lifecycle (paper §4.1/§4.2) ------------------------------------
-    def _run(self, kernel: str, device: int, maps: MapSpec, tag: str) -> Dict[str, jax.Array]:
+    def _run(self, kernel: str, device: int, maps: MapSpec, tag: str,
+             work: Optional[Dict[str, float]] = None) -> Dict[str, jax.Array]:
         with span(REGION, device=device, kernel=kernel, tag=tag):
-            return self._region(kernel, device, maps, tag)
+            return self._region(kernel, device, maps, tag, work)
 
-    def _region(self, kernel: str, device: int, maps: MapSpec, tag: str) -> Dict[str, jax.Array]:
+    def _region(self, kernel: str, device: int, maps: MapSpec, tag: str,
+                work: Optional[Dict[str, float]] = None) -> Dict[str, jax.Array]:
         pool = self.pool
         handles: Dict[str, Any] = {}   # name -> handle | [handles] (pytree)
         trees: Dict[str, Any] = {}     # name -> treedef for pytree maps
@@ -848,7 +856,7 @@ class TargetExecutor:
             result = pool.exec_kernel(device, kernel, buffers=handles, trees=trees,
                                       firstprivate=maps.firstprivate, tag=tag,
                                       skip_reads=tuple(ticketed),
-                                      extra_deps=tuple(exec_deps))
+                                      extra_deps=tuple(exec_deps), work=work)
             # the EXEC was *ordered* after its deps, not gated on their
             # success: a dep that failed between retain and EXEC left its
             # buffer unwritten, so the kernel just computed on garbage —

@@ -92,7 +92,9 @@ class TaskNode:
     introspection and future anti-dependency tracking, placement consults
     ``reads`` only).  ``device`` forces placement; ``tag`` overrides the
     region tag (pattern builders use it to keep their historical per-region
-    tags).
+    tags).  ``work`` declares what the node's kernel computes (counts by
+    name, such as cells and bytes); ``run_graph`` carries it onto the
+    EXEC's :class:`~repro.core.costmodel.ComputeRecord`.
     """
 
     name: str
@@ -103,6 +105,7 @@ class TaskNode:
     tag: Optional[str] = None
     reads: Tuple[str, ...] = ()
     writes: Tuple[str, ...] = ()
+    work: Optional[Dict[str, float]] = None
 
 
 class TaskGraph:
@@ -118,8 +121,8 @@ class TaskGraph:
         """Build from anything node-shaped (``TaskNode``, ``DagTask``, …).
 
         Duck-typed on ``name/kernel/deps/make_maps`` with optional
-        ``device/tag/reads/writes`` — the lowering entry point the pattern
-        builders use.
+        ``device/tag/reads/writes/work`` — the lowering entry point the
+        pattern builders use.
         """
         g = cls()
         for t in tasks:
@@ -134,7 +137,8 @@ class TaskGraph:
                 device=getattr(node, "device", None),
                 tag=getattr(node, "tag", None),
                 reads=tuple(getattr(node, "reads", ()) or ()),
-                writes=tuple(getattr(node, "writes", ()) or ()))
+                writes=tuple(getattr(node, "writes", ()) or ()),
+                work=getattr(node, "work", None))
         if node.name in self._nodes:
             raise ValueError(f"duplicate task {node.name!r}")
         if not node.reads:
@@ -795,7 +799,8 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
         attempts = 0
         while True:
             try:
-                ex.target(t.kernel, dev, maps, nowait=False, tag=rtag)
+                ex.target(t.kernel, dev, maps, nowait=False, tag=rtag,
+                          work=t.work)
                 return
             except (DeviceFailure, KeyError):
                 _absorb()
@@ -967,7 +972,8 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
         while True:
             try:
                 return ex.target(rec["t"].kernel, rec["dev"], rec["maps"],
-                                 nowait=False, tag=rec["tag"])
+                                 nowait=False, tag=rec["tag"],
+                                 work=rec["t"].work)
             except (DeviceFailure, KeyError) as err:
                 _absorb()
                 while True:
@@ -1003,6 +1009,7 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
         try:
             hmaps = (_peer_rewrite(t, hdev, rec["orig_maps"], htag)
                      if peer else rec["orig_maps"])
+            # no ``work``: the task's cells count once, on its primary
             hfut = ex.target(t.kernel, hdev, hmaps, nowait=True, tag=htag)
         except (DeviceFailure, KeyError):
             # the hedge could not even launch: undo its peer bookkeeping
@@ -1150,7 +1157,8 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
                         rec["start"] = time.monotonic()
                         rec["fut"] = ex.target(rec["t"].kernel, rec["dev"],
                                                rec["maps"], nowait=True,
-                                               tag=rec["tag"])
+                                               tag=rec["tag"],
+                                               work=rec["t"].work)
                         all_futs.append(rec["fut"])
                         nxt.append(rec)
                         continue
@@ -1312,7 +1320,8 @@ def run_graph(ex: TargetExecutor, graph: TaskGraph, *,
                     if nowait:
                         p["start"] = time.monotonic()
                         p["fut"] = ex.target(t.kernel, p["dev"], p["maps"],
-                                             nowait=True, tag=p["tag"])
+                                             nowait=True, tag=p["tag"],
+                                             work=t.work)
                         futs.append(p["fut"])
                         records.append(p)
                     else:

@@ -77,3 +77,22 @@ def test_mandelbrot_compiles(one_chip):
     h, w = STRIP
     _compile(lambda: mandelbrot(h, w, max_iter=MAX_ITER, row_offset=h,
                                 total_height=w), out_shardings=one_chip)
+
+
+@pytest.mark.parametrize("pairs,rows,width", [(32, 128, 128), (224, 384, 512),
+                                              (72, 1152, 1536)])
+def test_align_pairs_compiles(one_chip, pairs, rows, width):
+    """The BOTS alignment kernel at tasks of the ``alignment-bots-n100``
+    plan: four, two and one pair tiles per grid step."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                     "benchmarks"))
+    import bots_alignment as ba
+
+    def kernel(q, r, s, g, ge):
+        hot, prof = ba._operands(q, r, s)
+        return ba.forward_scores(hot, prof, g, ge, interpret=False)
+
+    sds = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    _compile(kernel, sds((pairs, rows)), sds((pairs, width)),
+             sds((ba.AA + 1, ba.AA + 1)), sds((pairs,)), sds(()))

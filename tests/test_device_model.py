@@ -325,3 +325,66 @@ def test_kernel_signature_is_read_at_registration_not_per_exec(monkeypatch):
     np.testing.assert_array_equal(res["out"], np.arange(4.0) + 1.0)
     assert reads == []
     pool.stop_all()
+
+
+MESH_SLICES = r"""
+import sys
+import jax, jax.numpy as jnp, numpy as np
+sys.path.insert(0, "benchmarks")
+import bots_alignment as ba
+from repro.core import (ClusterRuntime, DevicePool, KernelTable, MapSpec,
+                        RuntimeConfig, offload_strips)
+from repro.core.device import Command
+table = KernelTable()
+table.register("ids", lambda start: {"out": start + jnp.arange(4)})
+table.register("double", lambda x: {"out": 2 * x})
+table.register("align_pairs", ba.align_pairs)
+mesh = jax.make_mesh((4,), ("node",), devices=jax.devices()[:4])
+pool = DevicePool.from_mesh_slices(mesh, "node", table=table)
+chips = jax.devices()[:4]
+for d, dev in enumerate(pool.devices):
+    # the kernel's own output, before any write-back moves it
+    out = dev.execute(Command("EXEC", d, kernel_index=table.index_of("ids")),
+                      table, {"buffers": {}, "firstprivate": {"start": 1}})
+    assert out["out"].devices() == {chips[d]}, (d, out["out"].devices())
+    h = dev.store.alloc((3,), jnp.float32)
+    dev.store.write(h, jnp.arange(3.0))
+    out = dev.execute(Command("EXEC", d, kernel_index=table.index_of("double")),
+                      table, {"buffers": {"x": h}})
+    assert out["out"].devices() == {chips[d]}, (d, out["out"].devices())
+    dev.store.free(h)
+rt = ClusterRuntime(RuntimeConfig(), pool=pool)
+img = offload_strips(rt.ex, "ids", 16, lambda start, length: MapSpec(
+    firstprivate={"start": start},
+    from_={"out": jax.ShapeDtypeStruct((length,), jnp.int32)}))
+np.testing.assert_array_equal(np.asarray(img), np.arange(16))
+assert img.devices() == {chips[0]}
+bank = ba._data(10, 60, seed=5)
+bank.install(rt.ex)
+scores = np.asarray(ba.align_all_pairs(rt.ex, bank))
+rt.shutdown()
+rt = ClusterRuntime(RuntimeConfig(n_virtual=4), table=table)
+bank.install(rt.ex)
+np.testing.assert_array_equal(scores, np.asarray(ba.align_all_pairs(rt.ex, bank)))
+rt.shutdown()
+print("done")
+"""
+
+
+def test_mesh_slice_devices_run_their_kernels_on_their_own_chips():
+    """One device per chip of a mesh: a kernel runs on its device's chip,
+    whether its arguments are arrays or host scalars; strips computed on
+    four chips are gathered on the first; all-pairs scores over four chips
+    equal those over four virtual devices of one."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    proc = subprocess.run([sys.executable, "-c", MESH_SLICES], cwd=repo,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.rstrip().endswith("done")
