@@ -71,19 +71,22 @@ class ComputeRecord:
     seconds: float          # measured or modeled task compute time
     tag: str = ""
     kernel: str = ""        # registered kernel name (placement estimates)
-    # an EXEC's parts, on its worker's clock (None where not measured):
-    # ``seconds`` = dispatch_s (execute until the jitted call returns) +
-    # sync_s (block_until_ready); before it ran, gate_s (issue until its
-    # inputs settled) and queue_s (then until the worker took it);
+    # an EXEC's parts, on the host clock (None where not measured):
+    # ``seconds`` = dispatch_s (execute until the jitted call returns, on
+    # the worker) + sync_s (then until its completion thread saw the
+    # kernel finish); before it ran, gate_s (issue until its inputs
+    # settled) and queue_s (then until the worker took it);
     # zero_fills: unwritten slots its argument resolution zero-filled;
     # work: what the task declared it computes (``TaskNode.work``), None
-    # where it declared nothing
+    # where it declared nothing; inflight: the device's earlier EXECs
+    # dispatched but not completed when its dispatch began
     dispatch_s: Optional[float] = None
     sync_s: Optional[float] = None
     gate_s: Optional[float] = None
     queue_s: Optional[float] = None
     zero_fills: Optional[int] = None
     work: Optional[Dict[str, float]] = None
+    inflight: Optional[int] = None
 
 
 @dataclass
@@ -204,12 +207,13 @@ class CostModel:
                        gate_s: Optional[float] = None,
                        queue_s: Optional[float] = None,
                        zero_fills: Optional[int] = None,
-                       work: Optional[Dict[str, float]] = None) -> None:
+                       work: Optional[Dict[str, float]] = None,
+                       inflight: Optional[int] = None) -> None:
         with self._lock:
             self.compute.append(ComputeRecord(device, float(seconds), tag,
                                               kernel, dispatch_s, sync_s,
                                               gate_s, queue_s, zero_fills,
-                                              work))
+                                              work, inflight))
             self.events.append(Event("compute", device, tag=tag,
                                      seconds=float(seconds)))
 

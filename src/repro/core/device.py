@@ -334,18 +334,71 @@ class _WorkItem:
     ``cmd`` is the :class:`Command` served (None for the device-local
     write-back of a kernel result).  ``issued`` is stamped when the item is
     made, ``ready`` when the stream hands it to the worker's queue (its
-    dependencies settled); an EXEC's record reads both."""
+    dependencies settled); an EXEC's record reads both.  An item with a
+    ``settle`` (an EXEC) is not settled by the worker: ``fn`` only
+    dispatches, and the device's :class:`_Completion` settles the future
+    with ``settle(fn())`` once the chip has finished."""
 
-    __slots__ = ("fn", "future", "cmd", "op", "issued", "ready")
+    __slots__ = ("fn", "future", "cmd", "op", "issued", "ready", "settle")
 
     def __init__(self, fn: Callable[[], Any], future: "_cf.Future",
-                 cmd: Optional[Command] = None) -> None:
+                 cmd: Optional[Command] = None,
+                 settle: Optional[Callable[[Any], Any]] = None) -> None:
         self.fn = fn
         self.future = future
         self.cmd = cmd
         self.op = cmd.op if cmd is not None else "WRITEBACK"
         self.issued = time.perf_counter()
         self.ready = self.issued
+        self.settle = settle
+
+
+class _Completion:
+    """A device's completion path: one thread that takes the EXECs its
+    worker dispatched, in dispatch order, waits for the oldest (``settle``
+    blocks in ``block_until_ready``, it never polls) and settles its
+    future.  The worker meanwhile dispatches the next ready item.
+
+    ``dispatched`` is written by the worker alone and ``completed`` by this
+    thread alone; their difference is the device's kernels in flight."""
+
+    def __init__(self, device: int) -> None:
+        self._queue: "queue.SimpleQueue[Optional[Tuple[_WorkItem, Any]]]" = (
+            queue.SimpleQueue())
+        self.dispatched = 0
+        self.completed = 0
+        self.thread = threading.Thread(target=self._run,
+                                       name=f"omp-done{device}", daemon=True)
+        self.thread.start()
+
+    @property
+    def inflight(self) -> int:
+        return self.dispatched - self.completed
+
+    def hand_off(self, item: _WorkItem, dispatched: Any) -> None:
+        """On the worker: ``item`` is dispatched; settle it on completion."""
+        self.dispatched += 1
+        self._queue.put((item, dispatched))
+
+    def close(self) -> None:
+        """On the worker, after STOP: every EXEC has settled; end."""
+        self._queue.put(None)
+
+    def _run(self) -> None:
+        while True:
+            got = self._queue.get()
+            if got is None:
+                return
+            item, dispatched = got
+            try:
+                value, err = item.settle(dispatched), None
+            except BaseException as e:   # propagate to the issuer
+                value, err = None, e
+            self.completed += 1
+            if err is None:
+                item.future.set_result(value)
+            else:
+                item.future.set_exception(err)
 
 
 class StreamTicket:
@@ -397,8 +450,11 @@ class DevicePool:
     allocator replays the exact sequence the host mirror predicted under
     ``locks[d]`` — the handle-agreement property survives reordering.
     Ops that produce a value (EXEC, XFER_FROM) block on their command's
-    future.  ``stream_traces[d]`` records *execution* order (``trace`` keeps
-    issue order) so tests can assert producer-before-consumer.
+    future.  An EXEC's future settles when its kernel has completed, but
+    the worker only dispatches it: a completion thread per device waits for
+    the chip, so the worker dispatches the next ready EXEC while the chip
+    runs the last.  ``stream_traces[d]`` records *execution* order
+    (``trace`` keeps issue order) so tests can assert producer-before-consumer.
     """
 
     def __init__(self, devices: Sequence[NodeDevice], *,
@@ -451,20 +507,28 @@ class DevicePool:
         # a debugging/testing aid and must not grow with run length
         self.stream_traces: List["collections.deque[Command]"] = [
             collections.deque(maxlen=4096) for _ in self.devices]
-        self._workers = []
+        self._completions: List[_Completion] = []
+        self._workers: List[threading.Thread] = []
         for i in range(len(self.devices)):
-            t = threading.Thread(target=self._worker, args=(i,),
-                                 name=f"omp-dev{i}", daemon=True)
-            t.start()
-            self._workers.append(t)
+            self._start_device(i)
 
     # -- the per-device command-queue worker ---------------------------------
+    def _start_device(self, i: int) -> None:
+        """Start device ``i``'s completion thread and worker."""
+        self._completions.append(_Completion(i))
+        t = threading.Thread(target=self._worker, args=(i,),
+                             name=f"omp-dev{i}", daemon=True)
+        t.start()
+        self._workers.append(t)
+
     def _worker(self, device: int) -> None:
         q = self._queues[device]
+        done = self._completions[device]
         traces = self.stream_traces[device]
         while True:
             item = q.get()
             if item is None:                 # sentinel: queue closed
+                done.close()
                 return
             tag = ""
             if item.cmd is not None:
@@ -474,9 +538,19 @@ class DevicePool:
                 tag = item.cmd.tag
             with span(spans.CMD[item.op], device=device, tag=tag):
                 try:
-                    item.future.set_result(item.fn())
+                    value = item.fn()
+                    if item.settle is None:
+                        item.future.set_result(value)
+                    else:                    # dispatched: settled on completion
+                        done.hand_off(item, value)
                 except BaseException as e:   # propagate to the issuer
                     item.future.set_exception(e)
+
+    def _join(self, devices: Sequence[int]) -> None:
+        """Wait for stopped devices' workers and completion threads to end."""
+        for i in devices:
+            self._workers[i].join()
+            self._completions[i].thread.join()
 
     def _stream_deps(self, device: int, fut: "_cf.Future",
                      reads: Sequence[int], writes: Sequence[int],
@@ -552,7 +626,8 @@ class DevicePool:
     def _enqueue(self, device: int, fn: Callable[[], Any], *,
                  reads: Sequence[int] = (), writes: Sequence[int] = (),
                  extra_deps: Sequence["_cf.Future"] = (),
-                 cmd: Optional[Command] = None) -> _WorkItem:
+                 cmd: Optional[Command] = None,
+                 settle: Optional[Callable[[Any], Any]] = None) -> _WorkItem:
         # stopped-check and registration are atomic under the issue lock so
         # no item can land behind stop_all's close sentinel (a worker that
         # already exited would leave the submitter blocked forever)
@@ -565,7 +640,7 @@ class DevicePool:
             if len(out) > 64:                # prune settled commands in place
                 out[:] = [f for f in out if not f.done()]
             out.append(fut)
-        item = _WorkItem(fn, fut, cmd)
+        item = _WorkItem(fn, fut, cmd, settle)
         self._gate(device, item, deps)
         return item
 
@@ -869,30 +944,35 @@ class DevicePool:
                        "trees": trees or {},
                        "static_argnames": tuple(static_argnames)}
 
-            def run_exec():
+            def dispatch():                  # on the worker
                 dev = self.devices[device]
                 fills = dev.store.zero_fills
+                inflight = self._completions[device].inflight
                 t0 = time.perf_counter()
                 with span(spans.EXEC_DISPATCH):
                     out = dev.execute(cmd, self.table, payload)
                 t1 = time.perf_counter()
-                with span(spans.EXEC_SYNC):
-                    out = jax.block_until_ready(out)
-                t2 = time.perf_counter()
-                return out, t0, t1, t2, dev.store.zero_fills - fills
+                return out, t0, t1, dev.store.zero_fills - fills, inflight
 
-            item = self._enqueue(device, run_exec, reads=reads,
-                                 extra_deps=extra_deps, cmd=cmd)
+            def settle(dispatched):          # on the completion thread
+                out, t0, t1, fills, inflight = dispatched
+                with span(spans.EXEC_SYNC, device=device, tag=cmd.tag):
+                    out = jax.block_until_ready(out)
+                return out, t0, t1, time.perf_counter(), fills, inflight
+
+            item = self._enqueue(device, dispatch, reads=reads,
+                                 extra_deps=extra_deps, cmd=cmd,
+                                 settle=settle)
         with span(spans.REGION_EXEC_WAIT):
-            out, t0, t1, t2, fills = self._await_deadline(device,
-                                                          item.future, cmd)
+            out, t0, t1, t2, fills, inflight = self._await_deadline(
+                device, item.future, cmd)
         self._raise_async(device)
         self.cost.record_compute(device, t2 - t0, tag=tag or kernel_name,
                                  kernel=kernel_name, dispatch_s=t1 - t0,
                                  sync_s=t2 - t1,
                                  gate_s=item.ready - item.issued,
                                  queue_s=t0 - item.ready, zero_fills=fills,
-                                 work=work)
+                                 work=work, inflight=inflight)
         return out
 
     def _stop_device(self, i: int) -> Optional["_cf.Future"]:
@@ -924,6 +1004,7 @@ class DevicePool:
         for f in futs:
             if f is not None:
                 f.result()
+        self._join(range(len(self.devices)))
 
     # -- elastic pool membership (beyond-paper: nodes join/leave mid-job) -----
     def add_device(self, hostname: Optional[str] = None,
@@ -931,8 +1012,9 @@ class DevicePool:
         """Grow the pool by one device, placeable immediately.
 
         Appends every piece of per-device parallel state, starts the worker
-        thread, and replays ``install_global`` history onto the newcomer so
-        declare-target globals resolve there too.  Returns the new index.
+        and completion threads, and replays ``install_global`` history onto
+        the newcomer so declare-target globals resolve there too.  Returns
+        the new index.
 
         The newcomer is one more share of the placement every device of the
         pool already shares (virtual shares of one chip).  A pool spread
@@ -961,10 +1043,7 @@ class DevicePool:
         self._readers.append({})
         self._outstanding.append([])
         self.stream_traces.append(collections.deque(maxlen=4096))
-        t = threading.Thread(target=self._worker, args=(i,),
-                             name=f"omp-dev{i}", daemon=True)
-        t.start()
-        self._workers.append(t)
+        self._start_device(i)
         self.health.mark_healthy(i)          # fresh device, clean slate
         # declare-target globals must exist on every device (paper §4.2)
         for name, value in self._global_values.items():
@@ -990,6 +1069,7 @@ class DevicePool:
         for f in futs:
             if f is not None:
                 f.result()
+        self._join(departing)
         for i in departing:
             self._raise_async(i)             # surface anything left stashed
             for handles in self.globals.values():
@@ -1009,6 +1089,7 @@ class DevicePool:
         del self._outstanding[keep:]
         del self.stream_traces[keep:]
         del self._workers[keep:]
+        del self._completions[keep:]
 
     # -- declare-target globals (paper §4.2 last ¶) ---------------------------
     def install_global(self, name: str, value: Any, tag: str = "") -> int:

@@ -16,8 +16,10 @@ metadata and are encoded only while the profiler collects.
 ``omp.cmd.<OP>``         one stream item's service on its device worker
 ``omp.exec.dispatch``    an EXEC from ``execute`` until the jitted call
                          returns: lookup, argument filter, jit dispatch
-``omp.exec.sync``        the worker waiting for the chip
-                         (``block_until_ready``)
+``omp.exec.sync``        the device's completion thread waiting for an
+                         EXEC's kernel (``block_until_ready``), outside
+                         any ``omp.cmd.*`` span: the worker meanwhile
+                         serves the next item
 ``omp.jit.build``        the first call of a new jit wrapper (a compile)
 =======================  ===================================================
 

@@ -13,6 +13,7 @@ import pytest
 from repro.core import (ClusterRuntime, DagTask, DevicePool, KernelTable,
                         MapSpec, RuntimeConfig, TargetExecutor,
                         wavefront_offload)
+from repro.core.device import NodeDevice, StragglerTimeout
 from repro.optim import AdamW, AdamWConfig
 
 
@@ -408,3 +409,187 @@ def test_data_parallel_step_interleaves_with_handle_agreement():
         assert (sorted(rt.pool.mirrors[dev].live_handles())
                 == sorted(rt.pool.devices[dev].store.live_handles())), dev
     rt.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# pipelined EXECs: the worker dispatches, a completion thread waits
+# ---------------------------------------------------------------------------
+N_SPIN = 512
+
+
+def _spin(x):
+    """About a quarter of a second of CPU work for one small output."""
+    return {"out": jax.lax.fori_loop(0, 100, lambda i, a: jnp.tanh(a @ a), x)}
+
+
+def _spin_table():
+    table = KernelTable()
+    table.register("spin", _spin)
+    table.register("mix", lambda a, b: {"out": a + b})
+    return table
+
+
+def _spin_input(v):
+    return jnp.full((N_SPIN, N_SPIN), v / N_SPIN, jnp.float32)
+
+
+def _wait_for(cond, timeout=30.0):
+    end = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < end, "timed out"
+        time.sleep(0.005)
+
+
+def _log_device(dev, log):
+    """Append ("exec", tag) to ``log`` as ``dev`` starts each EXEC's
+    dispatch and ("install", handle) as a write-back installs a result."""
+    execute, install = dev.execute, dev.store.install
+
+    def logged_execute(cmd, table, payload=None):
+        if cmd.op == "EXEC":
+            log.append(("exec", cmd.tag, time.perf_counter()))
+        return execute(cmd, table, payload)
+
+    def logged_install(handle, value):
+        log.append(("install", handle, time.perf_counter()))
+        return install(handle, value)
+
+    dev.execute, dev.store.install = logged_execute, logged_install
+
+
+def test_independent_execs_on_one_device_overlap():
+    """The second of two queued EXECs is dispatched while the first's
+    kernel runs, its record counts the first in flight, and the results
+    are the serial path's."""
+    pool = DevicePool.virtual(1, table=_spin_table())
+    handles = []
+    for v in (1.0, 2.0):
+        h = pool.alloc(0, (N_SPIN, N_SPIN), jnp.float32)
+        pool.transfer_to(0, h, _spin_input(v))
+        handles.append(h)
+    serial = [pool.exec_kernel(0, "spin", buffers={"x": h}, tag=f"s{i}")
+              for i, h in enumerate(handles)]
+    log = []
+    _log_device(pool.devices[0], log)
+    gate = threading.Event()
+    pool._submit_async(0, gate.wait)             # holds the worker
+    with _cf.ThreadPoolExecutor(2) as tp:
+        futs = [tp.submit(pool.exec_kernel, 0, "spin", buffers={"x": h},
+                          tag=f"k{i}") for i, h in enumerate(handles)]
+        _wait_for(lambda: pool._queues[0].qsize() == 2)
+        gate.set()
+        outs = [f.result() for f in futs]
+    rec = {r.tag: r for r in pool.cost.compute}
+    assert rec["s0"].inflight == rec["s1"].inflight == 0
+    (_, first, t_first), (_, second, t_second) = log
+    assert rec[first].inflight == 0 and rec[second].inflight == 1
+    # dispatched well before the first kernel completed
+    assert t_second - t_first < rec[first].sync_s / 2
+    for out, ref in zip(outs, serial):
+        np.testing.assert_array_equal(out["out"], ref["out"])
+    pool.stop_all()
+
+
+def test_a_device_out_consumer_runs_after_its_producers_writeback():
+    """Peer mode keeps each output on the device (a ``device_out`` map);
+    the consumer's EXEC is dispatched only after both producers' results
+    were written back, and computes on them."""
+    rt = ClusterRuntime(RuntimeConfig(n_virtual=1, comm_mode="direct"),
+                        table=_spin_table())
+    x1, x2 = _spin_input(1.0), _spin_input(-0.5)
+    sds = jax.ShapeDtypeStruct((N_SPIN, N_SPIN), jnp.float32)
+    tasks = [
+        DagTask("p1", "spin", (), lambda r: MapSpec(to={"x": x1},
+                                                    from_={"out": sds})),
+        DagTask("p2", "spin", (), lambda r: MapSpec(to={"x": x2},
+                                                    from_={"out": sds})),
+        DagTask("c", "mix", ("p1", "p2"),
+                lambda r: MapSpec(to={"a": r["p1"], "b": r["p2"]},
+                                  from_={"out": sds})),
+    ]
+    rt.wavefront_offload(tasks, nowait=True, peer=True, tag="warm")
+    log = []
+    _log_device(rt.pool.devices[0], log)
+    res = rt.wavefront_offload(tasks, nowait=True, peer=True, tag="t")
+    rt.shutdown()
+    what = [(k, str(v).rsplit(":", 1)[-1]) for k, v, _ in log]
+    c_at = what.index(("exec", "c"))
+    last_p = max(what.index(("exec", "p1")), what.index(("exec", "p2")))
+    assert sum(k == "install" for k, _ in what[last_p:c_at]) >= 2
+    spin = jax.jit(_spin)
+    ref = np.asarray(spin(x1)["out"]) + np.asarray(spin(x2)["out"])
+    np.testing.assert_allclose(res["c"], ref, rtol=1e-6, atol=1e-6)
+
+
+def test_a_kernel_outlasting_the_deadline_raises_though_its_dispatch_returned():
+    """The pool deadline covers the kernel's run, not only its dispatch."""
+    pool = DevicePool.virtual(1, table=_spin_table())
+    h = pool.alloc(0, (N_SPIN, N_SPIN), jnp.float32)
+    pool.transfer_to(0, h, _spin_input(1.0))
+    for _ in range(2):                           # compile, then time
+        pool.exec_kernel(0, "spin", buffers={"x": h})
+    pool.deadline_s = pool.cost.compute[-1].seconds / 4
+    with pytest.raises(StragglerTimeout):
+        pool.exec_kernel(0, "spin", buffers={"x": h})
+    done = pool._completions[0]
+    assert done.dispatched == 3                  # the worker had let it go
+    assert pool.straggler_timeouts == {"EXEC": 1}
+    pool.sync()                                  # settles, harmlessly, later
+    assert done.inflight == 0 and len(pool.cost.compute) == 2
+    pool.stop_all()
+
+
+class _FailsOnCompletion:
+    """A kernel output whose run fails only when waited for."""
+
+    def block_until_ready(self):
+        raise RuntimeError("kernel failed on the chip")
+
+
+class _FaultyChipDevice(NodeDevice):
+    def execute(self, cmd, table, payload=None):
+        out = super().execute(cmd, table, payload)
+        if cmd.op == "EXEC" and cmd.tag == "bad":
+            return {"out": _FailsOnCompletion()}
+        return out
+
+
+def test_a_failure_seen_on_completion_reaches_the_issuer():
+    """A kernel that fails after its dispatch returned raises from the
+    completion thread into its EXEC; the device serves on."""
+    dev = _FaultyChipDevice(0, jax_device=jax.devices()[0])
+    pool = DevicePool([dev], table=_spin_table())
+    h = pool.alloc(0, (4, 4), jnp.float32)
+    pool.transfer_to(0, h, np.ones((4, 4), np.float32))
+    with pytest.raises(RuntimeError, match="failed on the chip"):
+        pool.exec_kernel(0, "mix", buffers={"a": h, "b": h}, tag="bad")
+    out = pool.exec_kernel(0, "mix", buffers={"a": h, "b": h})
+    np.testing.assert_array_equal(out["out"], np.full((4, 4), 2.0))
+    done = pool._completions[0]
+    assert done.dispatched == done.completed == 2
+    assert [r.tag for r in pool.cost.compute] == ["mix"]
+    pool.stop_all()
+
+
+def test_stop_all_waits_for_inflight_completions_and_ends_every_thread():
+    pool = DevicePool.virtual(1, table=_spin_table())
+    added = pool.add_device()
+    handles = {}
+    for d in (0, added):
+        handles[d] = pool.alloc(d, (N_SPIN, N_SPIN), jnp.float32)
+        pool.transfer_to(d, handles[d], _spin_input(1.0))
+        pool.exec_kernel(d, "spin", buffers={"x": handles[d]})   # compiles
+    with _cf.ThreadPoolExecutor(2) as tp:
+        futs = [tp.submit(pool.exec_kernel, d, "spin",
+                          buffers={"x": handles[d]}) for d in (0, added)]
+        _wait_for(lambda: all(pool._completions[d].dispatched == 2
+                              for d in (0, added)))
+        pool.stop_all()
+        assert all(f.done() for d in (0, added)
+                   for f in pool._outstanding[d])
+        outs = [f.result() for f in futs]
+    np.testing.assert_array_equal(outs[0]["out"], outs[1]["out"])
+    threads = pool._workers + [c.thread for c in pool._completions]
+    assert len(threads) == 4 and not any(t.is_alive() for t in threads)
+    with pytest.raises(RuntimeError, match="stopped"):
+        pool.exec_kernel(0, "spin", buffers={"x": handles[0]})

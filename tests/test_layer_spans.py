@@ -102,6 +102,7 @@ def test_exec_records_split_into_dispatch_and_sync():
         assert rec.dispatch_s > 0 and rec.sync_s >= 0
         assert rec.dispatch_s + rec.sync_s == pytest.approx(rec.seconds)
         assert rec.gate_s >= 0 and rec.queue_s >= 0
+        assert rec.inflight == 0                 # each ran alone
     pool.stop_all()
 
 
@@ -126,8 +127,13 @@ def test_exec_records_stay_whole_under_contention():
     for rec in pool.cost.compute:
         assert rec.gate_s >= 0.0 and rec.queue_s >= 0.0
         assert rec.dispatch_s + rec.sync_s == pytest.approx(rec.seconds)
+        assert rec.inflight >= 0
     pool.sync()
     assert sum(c.op == "EXEC" for t in pool.stream_traces for c in t) == 200
+    # each device's worker and completion thread counted every EXEC once
+    done = pool._completions
+    assert [c.dispatched for c in done] == [c.completed for c in done]
+    assert sum(c.dispatched for c in done) == 200
     pool.stop_all()
 
 
@@ -222,7 +228,7 @@ EXPECTED_PARENT = {
     spans.REGION: None,
     spans.REGION_EXEC_WAIT: spans.REGION,
     spans.EXEC_DISPATCH: spans.CMD["EXEC"],
-    spans.EXEC_SYNC: spans.CMD["EXEC"],
+    spans.EXEC_SYNC: None,                # on the device's completion thread
     spans.JIT_BUILD: spans.EXEC_DISPATCH,
 }
 
@@ -261,6 +267,21 @@ def test_commands_are_spanned_by_op_on_their_workers(dag_trace, op):
     assert len(lines_of) == 2
     assert all(len(v) == 1 for v in lines_of.values())
     assert lines_of[0] != lines_of[1]
+
+
+def test_exec_syncs_run_on_a_completion_thread_per_device(dag_trace):
+    """The wait for the chip is off the workers: each device's
+    ``omp.exec.sync`` spans sit on one line that serves no command."""
+    cmd_lines = {i for i, line in dag_trace.items() for ev in line
+                 if ev[0].startswith("omp.cmd.")}
+    sync_lines = {}
+    for i, line in dag_trace.items():
+        for ev in line:
+            if ev[0] == spans.EXEC_SYNC:
+                sync_lines.setdefault(ev[3]["device"], set()).add(i)
+    assert sorted(sync_lines) == [0, 1]
+    assert all(len(v) == 1 for v in sync_lines.values())
+    assert not set().union(*sync_lines.values()) & cmd_lines
 
 
 def test_exec_spans_name_their_kernel_in_the_tag(dag_trace):
